@@ -16,6 +16,7 @@ from benchmarks import (bench_architectures, bench_autoscale, bench_chaos,
                         bench_rebalance, bench_recall_latency,
                         bench_roofline_stages, bench_scheduler,
                         bench_semantic_cache, bench_sharded)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "fig1_roofline_stages": bench_roofline_stages.run,
@@ -38,6 +39,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=list(BENCHES), default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     summary = []
     for name, fn in BENCHES.items():

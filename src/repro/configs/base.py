@@ -160,8 +160,9 @@ class VectorPoolConfig:
     # fused stepping: K extend steps per device dispatch (lax.scan) — the
     # host syncs completion masks once per chunk instead of every step
     extend_chunk: int = 4
-    # distance-stage compute path: "slot_gather" (row-wise O(T·d), default)
-    # or "matmul_onehot" (original O(T·R·d) MXU path, kept as oracle)
+    # distance-stage compute path: "slot_gather" (row-wise O(T·d), default;
+    # the Pallas kernel's form) or "matmul_onehot" (original O(T·R·d) form,
+    # kept as oracle; jnp path only)
     distance_mode: str = "slot_gather"
     # scheduler (per §3.3)
     r_min: float = 0.1

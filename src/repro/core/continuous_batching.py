@@ -132,6 +132,18 @@ DEFAULT_PARAMS = SlotParams()
 # ---------------------------------------------------------------------------
 
 
+def _place_corpus(db, use_pallas: bool):
+    """The engine's device copy of the corpus: (N, d) for the jnp path,
+    the distance kernel's (N, 1, d_pad) layout for the Pallas path."""
+    return kernel_ops.corpus_layout(db) if use_pallas else jnp.asarray(db)
+
+
+def _corpus_rows(rows, dim: int):
+    """Gathered corpus rows in either ``_place_corpus`` layout → (n, dim)
+    float32 (drops the kernel layout's unit axis and zero lanes)."""
+    return rows.reshape(rows.shape[0], -1)[:, :dim].astype(jnp.float32)
+
+
 def _seed_request(db, qvec, entry_key, entry_lo, entry_hi, *, top_m: int,
                   visited_slots: int, num_entries: int, metric: str):
     """Shared seeding body for ``admit`` / ``admit_many``: random entry
@@ -142,7 +154,7 @@ def _seed_request(db, qvec, entry_key, entry_lo, entry_hi, *, top_m: int,
     are traced scalars, so heterogeneous segments share one compile."""
     entries = jax.random.randint(entry_key, (num_entries,), entry_lo,
                                  entry_hi)
-    x = db[entries].astype(jnp.float32)
+    x = _corpus_rows(db[entries], qvec.shape[-1])
     q = qvec[None].astype(jnp.float32)
     if metric == "l2":
         d = jnp.sum((x - q) ** 2, axis=-1)
@@ -345,9 +357,12 @@ def _extend_impl(state: EngineState, db, graph, *, p: int, task_batch: int,
 
     # ---- stage 4: ONE fixed-shape distance operator ----------------------
     if use_pallas:
+        if distance_mode != "slot_gather":
+            raise ValueError(f"distance_mode {distance_mode!r} has no Pallas "
+                             "kernel; it runs on the jnp path only "
+                             "(use_pallas=False)")
         dists = kernel_ops.distance_tasks(db, state.query_vecs, task_ids_p,
-                                          task_slot_p, metric=metric,
-                                          mode=distance_mode)
+                                          task_slot_p, metric=metric)
     elif distance_mode == "matmul_onehot":
         dists = kernel_ref.distance_tasks_onehot_ref(
             db, state.query_vecs, task_ids_p, task_slot_p, metric=metric)
@@ -441,7 +456,9 @@ class ContinuousBatchingEngine:
                  use_pallas: Optional[bool] = None, seed: int = 0,
                  corpus_rows: Optional[int] = None):
         self.cfg = cfg
-        self.db = jnp.asarray(db)
+        self.use_pallas = (jax.default_backend() == "tpu"
+                           if use_pallas is None else use_pallas)
+        self.db = _place_corpus(db, self.use_pallas)
         self.graph = jnp.asarray(graph)
         # rows [0, corpus_n) are the frozen corpus segment; rows beyond are
         # a growable segment (online inserts) that default admissions must
@@ -451,8 +468,6 @@ class ContinuousBatchingEngine:
         self.free_slots = list(range(cfg.max_requests))[::-1]
         self.slot_request = {}  # slot -> request id
         self.slot_topk = {}  # slot -> per-slot top-k truncation (optional)
-        self.use_pallas = (jax.default_backend() == "tpu"
-                           if use_pallas is None else use_pallas)
         self.distance_mode = cfg.distance_mode
         self.extend_chunk = max(1, cfg.extend_chunk)
         self._key = jax.random.PRNGKey(seed)
@@ -543,8 +558,9 @@ class ContinuousBatchingEngine:
         """Swap in grown index arrays (online inserts). In-flight searches
         simply see the new rows on their next extend — semantically a
         regular ANN index update. A capacity growth (shape change) costs
-        one fresh jit specialisation, bounded O(log capacity) times."""
-        self.db = jnp.asarray(db)
+        one fresh jit specialisation, bounded O(log capacity) times. On the
+        Pallas path each swap also re-lays-out the corpus (one copy)."""
+        self.db = _place_corpus(db, self.use_pallas)
         self.graph = jnp.asarray(graph)
         if corpus_rows is not None:
             self.corpus_n = corpus_rows
@@ -738,7 +754,7 @@ def _seed_request_g(dbs, g, qvec, entry_key, entry_lo, entry_hi, *,
     first would materialise a (B, N, d) copy under vmap."""
     entries = jax.random.randint(entry_key, (num_entries,), entry_lo,
                                  entry_hi)
-    x = dbs[g, entries].astype(jnp.float32)
+    x = _corpus_rows(dbs[g, entries], qvec.shape[-1])
     q = qvec[None].astype(jnp.float32)
     if metric == "l2":
         d = jnp.sum((x - q) ** 2, axis=-1)
@@ -921,6 +937,9 @@ class GroupEngine:
         self.cfg = cfg
         self.use_pallas = (jax.default_backend() == "tpu"
                            if use_pallas is None else use_pallas)
+        # per-row shape of the stacked corpus (see ``_place_corpus``)
+        self._row_shape = tuple(_place_corpus(
+            np.zeros((1, cfg.dim), np.float32), self.use_pallas).shape[1:])
         self.state: Optional[EngineState] = None
         self.dbs = None
         self.graphs = None
@@ -942,8 +961,8 @@ class GroupEngine:
             lambda x: jnp.broadcast_to(x[None], (add,) + x.shape), init)
         if self.state is None:
             self.state = jax.tree_util.tree_map(jnp.array, fresh)
-            self.dbs = jnp.zeros((new_cap, max(self.n_max, 1),
-                                  self.cfg.dim), jnp.float32)
+            self.dbs = jnp.zeros((new_cap, max(self.n_max, 1))
+                                 + self._row_shape, jnp.float32)
             self.graphs = jnp.full((new_cap, max(self.n_max, 1),
                                     self.cfg.graph_degree), -1, jnp.int32)
             self.n_max = max(self.n_max, 1)
@@ -969,7 +988,7 @@ class GroupEngine:
             new_n *= 2
         pad = new_n - self.n_max
         self.dbs = jnp.concatenate(
-            [self.dbs, jnp.zeros((self.g_cap, pad, self.cfg.dim),
+            [self.dbs, jnp.zeros((self.g_cap, pad) + self._row_shape,
                                  jnp.float32)], axis=1)
         self.graphs = jnp.concatenate(
             [self.graphs, jnp.full((self.g_cap, pad,
@@ -994,8 +1013,8 @@ class GroupEngine:
     def write_lane_index(self, lane: int, db, graph):
         self._ensure_rows(db.shape[0])
         self.dbs, self.graphs = _set_lane_index(
-            self.dbs, self.graphs, jnp.int32(lane), jnp.asarray(db),
-            jnp.asarray(graph))
+            self.dbs, self.graphs, jnp.int32(lane),
+            _place_corpus(db, self.use_pallas), jnp.asarray(graph))
 
     # --------------------------------------------------------- device ops
     def _pad_pairs(self, entries):

@@ -24,34 +24,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ---------------------------------------------------------------------------
-# jax version compat: shard_map moved from jax.experimental.shard_map to the
-# jax namespace (and renamed check_rep -> check_vma) across 0.4.x -> 0.5+;
-# jax.lax.axis_size is likewise absent on 0.4.x. All repro code routes
-# through these two helpers instead of touching jax.shard_map directly.
-# ---------------------------------------------------------------------------
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              **kwargs):
-    """``jax.shard_map`` where available, else the 0.4.x experimental one
-    (translating the ``check_vma`` kwarg back to its old ``check_rep``
-    name)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kwargs)
-
-
-def axis_size(name) -> int:
-    """Static mapped-axis size inside shard_map. On 0.4.x (no
-    ``jax.lax.axis_size``) ``psum(1, name)`` constant-folds to the size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 # leaf-path regex -> spec template for the TRAILING dims (leading stack dims

@@ -9,23 +9,21 @@ shape → no recompiles).
 TPU adaptation (DESIGN.md §3): the GPU warp-gather becomes a *burst DMA
 gather* — task db-row ids arrive via scalar prefetch (SMEM), each grid step
 issues TASK_BLOCK row copies HBM→VMEM back-to-back on per-row DMA
-semaphores, then waits. Two compute paths, selected by ``mode`` (the
-engine's ``VectorPoolConfig.distance_mode`` knob):
+semaphores, then waits. The owning query row of each task is selected from
+the VMEM-resident (R, d) query block by a (R, TB) one-hot matmul on the MXU,
+and the distance is a row-wise VPU reduction over the two (TB, d) blocks.
 
-  ``matmul_onehot`` (the original path, kept as oracle) — an MXU matmul of
-  the gathered block against the resident (R, d) query block followed by a
-  one-hot slot-select (VPU). Does O(TB·R·d) MACs to use O(TB·d) of them:
-  R× wasted MXU work per task.
+Layout (what Mosaic accepts): a one-row DMA may not split a tiled
+dimension, so the corpus lives on the device as ``(N, 1, d_pad)`` — each
+row its own (1, 128)-tiled slab — with ``d_pad`` the width rounded up to the
+128-lane tile. ``corpus_layout`` builds that array once, where the corpus is
+placed on the device; zero lanes leave l2 and ip distances exact (queries
+are zero-padded to match per call, an (R, d_pad) copy). Task ids and slots
+travel as one (2, T) operand and the output is (1, T), so every blocked
+operand keeps XLA's own 2-D tiling.
 
-  ``slot_gather`` (default) — the owning query row is gathered per task
-  from the VMEM-resident (R, d) query block via a local row copy
-  (task_slot also arrives via scalar prefetch; no extra HBM traffic), and
-  the distance is a row-wise VPU reduction over the two gathered blocks.
-  O(TB·d) work total; no (TB, R) intermediate, no one-hot select.
-
-Arithmetic intensity per task ≈ d MACs / d·4 bytes ⇒ memory-bound either
-way, matching the paper's roofline placement of ANN next to decode — which
-is exactly why burning R× MXU FLOPs buys nothing and ``slot_gather`` wins.
+Arithmetic intensity per task ≈ d MACs / d·4 bytes ⇒ memory-bound, matching
+the paper's roofline placement of ANN next to decode.
 """
 from __future__ import annotations
 
@@ -37,201 +35,122 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DUMMY_DIST = 1e30
+LANES = 128
 
 
-def _distance_kernel(task_ids_sref, db_ref, queries_ref, qnorm_ref,
-                     ids_ref, slot_ref, out_ref, gather, sems, *,
-                     task_block: int, metric: str):
-    """One grid step = one task block.
+def corpus_layout(db):
+    """(N, d) corpus → the kernel's (N, 1, d_pad) float32 layout, d_pad the
+    next multiple of 128 (zero lanes). Call once per placed corpus: the
+    kernel never re-lays-out or copies the corpus itself."""
+    db = jnp.asarray(db, jnp.float32)
+    pad = -db.shape[1] % LANES
+    if pad:
+        db = jnp.pad(db, ((0, 0), (0, pad)))
+    return db[:, None, :]
+
+
+def _distance_kernel(task_ids_sref, db_ref, queries_ref, meta_ref, out_ref,
+                     xgather, sems, *, task_block: int, metric: str):
+    """One grid step = one task block, O(TB·d) VPU work.
 
     task_ids_sref: (T,) int32 in SMEM (scalar prefetch, DMA addressing)
-    db_ref:        (N, d) in ANY (stays in HBM; rows DMA'd on demand)
-    queries_ref:   (R, d) VMEM — request-slot query vectors (resident)
-    qnorm_ref:     (1, R) VMEM — precomputed |q|^2 per slot
-    ids_ref:       (task_block,) VMEM — same ids, for dummy masking
-    slot_ref:      (task_block,) VMEM — owning slot per task
-    out_ref:       (task_block,) VMEM distances
-    gather:        (task_block, d) VMEM scratch
+    db_ref:        (N, 1, d) in ANY (stays in HBM; rows DMA'd on demand)
+    queries_ref:   (R, d) VMEM — resident query block
+    meta_ref:      (2, task_block) VMEM — row 0 task ids (dummy mask),
+                   row 1 owning slot per task
+    out_ref:       (1, task_block) VMEM distances
+    xgather:       (task_block, 1, d) VMEM scratch
     sems:          (task_block,) DMA semaphores
     """
-    blk = pl.program_id(0)
-    base = blk * task_block
+    base = pl.program_id(0) * task_block
 
     # ---- burst DMA gather: start all row copies, then wait all ----------
     def start(i, carry):
         row = jnp.maximum(task_ids_sref[base + i], 0)  # dummies fetch row 0
-        pltpu.make_async_copy(
-            db_ref.at[pl.ds(row, 1)], gather.at[pl.ds(i, 1)], sems.at[i]
-        ).start()
+        pltpu.make_async_copy(db_ref.at[pl.ds(row, 1)],
+                              xgather.at[pl.ds(i, 1)], sems.at[i]).start()
         return carry
 
     jax.lax.fori_loop(0, task_block, start, 0)
 
     def wait(i, carry):
-        row = jnp.maximum(task_ids_sref[base + i], 0)
-        pltpu.make_async_copy(
-            db_ref.at[pl.ds(row, 1)], gather.at[pl.ds(i, 1)], sems.at[i]
-        ).wait()
+        # a wait needs only the semaphore and the copy's size
+        pltpu.make_async_copy(db_ref.at[pl.ds(0, 1)],
+                              xgather.at[pl.ds(i, 1)], sems.at[i]).wait()
         return carry
 
     jax.lax.fori_loop(0, task_block, wait, 0)
 
-    # ---- distances: MXU matmul + one-hot slot select (VPU) --------------
-    x = gather[...].astype(jnp.float32)  # (TB, d)
-    q = queries_ref[...].astype(jnp.float32)  # (R, d)
-    xq = jax.lax.dot_general(x, q, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (TB, R)
+    # ---- owning query rows: exact one-hot select on the MXU -------------
+    meta = meta_ref[...]
+    ids, slots = meta[0:1, :], meta[1:2, :]  # (1, TB) each
+    q = queries_ref[...]  # (R, d)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], task_block), 0)
+              == slots).astype(jnp.float32)  # (R, TB)
+    qsel = jax.lax.dot_general(onehot, q, (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)  # (TB, d)
 
-    R = q.shape[0]
-    onehot = (slot_ref[...][:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (task_block, R), 1))
-    sel_xq = jnp.sum(jnp.where(onehot, xq, 0.0), axis=1)  # (TB,)
-
+    # ---- distances: row-wise VPU reduction -------------------------------
+    x = xgather[...].reshape(task_block, q.shape[1])  # (TB, d)
     if metric == "l2":
-        xnorm = jnp.sum(x * x, axis=1)
-        sel_qn = jnp.sum(jnp.where(onehot, qnorm_ref[...], 0.0), axis=1)
-        dist = xnorm - 2.0 * sel_xq + sel_qn
-    elif metric == "ip":
-        dist = -sel_xq
-    else:
-        raise ValueError(metric)
-
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, dist, DUMMY_DIST)
-
-
-def _distance_kernel_gather(task_ids_sref, task_slot_sref, db_ref,
-                            queries_ref, ids_ref, out_ref, xgather, qgather,
-                            xsems, qsems, *, task_block: int, metric: str):
-    """Slot-gather path: one grid step = one task block, O(TB·d) work.
-
-    task_ids_sref:  (T,) int32 in SMEM (scalar prefetch, db DMA addressing)
-    task_slot_sref: (T,) int32 in SMEM (scalar prefetch, query row select)
-    db_ref:         (N, d) in ANY (stays in HBM; rows DMA'd on demand)
-    queries_ref:    (R, d) VMEM — resident query block (fits easily; no
-                    per-task HBM traffic for queries, the row copy below is
-                    a local VMEM→VMEM DMA)
-    ids_ref:        (task_block,) VMEM — same ids, for dummy masking
-    out_ref:        (task_block,) VMEM distances
-    xgather/qgather: (task_block, d) VMEM scratch (db rows / query rows)
-    xsems/qsems:    (task_block,) DMA semaphores
-    """
-    blk = pl.program_id(0)
-    base = blk * task_block
-
-    # ---- burst gather: db row from HBM + owning query row from the -------
-    # resident VMEM block (dummies clamp to row/slot 0, masked at the end)
-    def start(i, carry):
-        row = jnp.maximum(task_ids_sref[base + i], 0)
-        pltpu.make_async_copy(
-            db_ref.at[pl.ds(row, 1)], xgather.at[pl.ds(i, 1)], xsems.at[i]
-        ).start()
-        slot = jnp.maximum(task_slot_sref[base + i], 0)
-        pltpu.make_async_copy(
-            queries_ref.at[pl.ds(slot, 1)], qgather.at[pl.ds(i, 1)],
-            qsems.at[i]
-        ).start()
-        return carry
-
-    jax.lax.fori_loop(0, task_block, start, 0)
-
-    def wait(i, carry):
-        row = jnp.maximum(task_ids_sref[base + i], 0)
-        pltpu.make_async_copy(
-            db_ref.at[pl.ds(row, 1)], xgather.at[pl.ds(i, 1)], xsems.at[i]
-        ).wait()
-        slot = jnp.maximum(task_slot_sref[base + i], 0)
-        pltpu.make_async_copy(
-            queries_ref.at[pl.ds(slot, 1)], qgather.at[pl.ds(i, 1)],
-            qsems.at[i]
-        ).wait()
-        return carry
-
-    jax.lax.fori_loop(0, task_block, wait, 0)
-
-    # ---- distances: row-wise VPU reduction, no (TB, R) intermediate ------
-    x = xgather[...].astype(jnp.float32)  # (TB, d)
-    q = qgather[...].astype(jnp.float32)  # (TB, d)
-    if metric == "l2":
-        diff = x - q
+        diff = x - qsel
         dist = jnp.sum(diff * diff, axis=1)
     elif metric == "ip":
-        dist = -jnp.sum(x * q, axis=1)
+        dist = -jnp.sum(x * qsel, axis=1)
     else:
         raise ValueError(metric)
 
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, dist, DUMMY_DIST)
+    out_ref[...] = jnp.where(ids >= 0, dist[None, :], DUMMY_DIST)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "task_block",
-                                             "interpret", "mode"))
-def distance_tasks(db, queries, task_ids, task_slot, *, metric: str = "l2",
-                   task_block: int = 256, interpret: bool = True,
-                   mode: str = "slot_gather"):
-    """Fixed-shape distance stage.
+                                             "interpret"))
+def distance_tasks(corpus, queries, task_ids, task_slot, *, metric: str = "l2",
+                   task_block: int = 256, interpret: bool = True):
+    """Fixed-shape distance stage; oracle is ``ref.distance_tasks_ref``.
 
-    ``mode="slot_gather"`` (default): row-wise O(T·d) path; oracle is
-    ``ref.distance_tasks_ref``. ``mode="matmul_onehot"``: the original
-    O(T·R·d) MXU path, kept as oracle (``ref.distance_tasks_onehot_ref``).
-
-    db (N,d) · queries (R,d) · task_ids/task_slot (T,) int32 with
-    T % task_block == 0 (the engine pads with dummies; id −1 = dummy).
+    corpus (N, 1, d_pad) from ``corpus_layout`` · queries (R, d), d ≤ d_pad ·
+    task_ids/task_slot (T,) int32 (the engine pads with dummies; id −1 =
+    dummy). ``task_block`` is capped at T and must divide it.
     Returns (T,) float32 distances (dummies = DUMMY_DIST).
     """
+    if (corpus.ndim != 3 or corpus.shape[1] != 1
+            or corpus.shape[2] % LANES or corpus.dtype != jnp.float32):
+        raise ValueError(
+            f"distance_tasks takes the corpus as float32 (N, 1, k*{LANES}) "
+            f"(build it once with corpus_layout), got {corpus.dtype}"
+            f"{tuple(corpus.shape)}")
     T = task_ids.shape[0]
-    assert T % task_block == 0, (T, task_block)
-
-    if mode == "slot_gather":
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # task_ids + task_slot (SMEM addressing)
-            grid=(T // task_block,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),  # db stays in HBM
-                pl.BlockSpec(queries.shape, lambda i, *_: (0, 0)),  # resident
-                pl.BlockSpec((task_block,), lambda i, *_: (i,)),  # ids (mask)
-            ],
-            out_specs=pl.BlockSpec((task_block,), lambda i, *_: (i,)),
-            scratch_shapes=[
-                pltpu.VMEM((task_block, db.shape[1]), jnp.float32),
-                pltpu.VMEM((task_block, db.shape[1]), jnp.float32),
-                pltpu.SemaphoreType.DMA((task_block,)),
-                pltpu.SemaphoreType.DMA((task_block,)),
-            ],
-        )
-        kernel = functools.partial(_distance_kernel_gather,
-                                   task_block=task_block, metric=metric)
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((T,), jnp.float32),
-            interpret=interpret,
-        )(task_ids, task_slot, db.astype(jnp.float32),
-          queries.astype(jnp.float32), task_ids)
-
-    if mode != "matmul_onehot":
-        raise ValueError(f"unknown distance mode: {mode!r}")
-    qnorm = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1)[None, :]  # (1,R)
+    task_block = min(task_block, T)
+    if T % task_block:
+        raise ValueError(f"task count {T} is not a multiple of the task "
+                         f"block {task_block}")
+    d_pad = corpus.shape[2]
+    queries = queries.astype(jnp.float32)
+    queries = jnp.pad(queries, ((0, 0), (0, d_pad - queries.shape[1])))
+    meta = jnp.stack([task_ids, task_slot]).astype(jnp.int32)  # (2, T)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # task_ids (SMEM, DMA addressing)
         grid=(T // task_block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # db stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # corpus stays in HBM
             pl.BlockSpec(queries.shape, lambda i, *_: (0, 0)),  # resident
-            pl.BlockSpec(qnorm.shape, lambda i, *_: (0, 0)),
-            pl.BlockSpec((task_block,), lambda i, *_: (i,)),  # ids (mask)
-            pl.BlockSpec((task_block,), lambda i, *_: (i,)),  # slots
+            pl.BlockSpec((2, task_block), lambda i, *_: (0, i)),
         ],
-        out_specs=pl.BlockSpec((task_block,), lambda i, *_: (i,)),
+        out_specs=pl.BlockSpec((1, task_block), lambda i, *_: (0, i)),
         scratch_shapes=[
-            pltpu.VMEM((task_block, db.shape[1]), jnp.float32),
+            pltpu.VMEM((task_block, 1, d_pad), jnp.float32),
             pltpu.SemaphoreType.DMA((task_block,)),
         ],
     )
     kernel = functools.partial(_distance_kernel, task_block=task_block,
                                metric=metric)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, T), jnp.float32),
         interpret=interpret,
-    )(task_ids, db.astype(jnp.float32), queries, qnorm, task_ids, task_slot)
+    )(task_ids.astype(jnp.int32), corpus, queries, meta)
+    return out[0]

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) every kernel runs in ``interpret=True`` mode — the
-kernel body executes as pure JAX ops, validating BlockSpec tiling and
-semantics. On a TPU backend the same call sites compile to Mosaic.
+On CPU every kernel runs in ``interpret=True`` mode — the kernel body
+executes as pure JAX ops, validating semantics but not Mosaic's tiling
+rules (tests/test_tpu_compile.py compiles for a described TPU for that).
+On a TPU backend the same call sites compile to Mosaic.
 """
 from __future__ import annotations
 
@@ -94,11 +95,15 @@ def finalize_partial_topk(buf_ids, buf_dists, rows_f, *, k: int):
             m_ids, m_d)
 
 
-def distance_tasks(db, queries, task_ids, task_slot, metric: str = "l2",
-                   task_block: int = 256, mode: str = "slot_gather"):
-    return _dist.distance_tasks(db, queries, task_ids, task_slot,
+corpus_layout = _dist.corpus_layout
+
+
+def distance_tasks(corpus, queries, task_ids, task_slot, metric: str = "l2",
+                   task_block: int = 256):
+    """``corpus`` is in the kernel's layout (``corpus_layout``)."""
+    return _dist.distance_tasks(corpus, queries, task_ids, task_slot,
                                 metric=metric, task_block=task_block,
-                                interpret=_interpret(), mode=mode)
+                                interpret=_interpret())
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,

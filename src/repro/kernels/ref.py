@@ -37,8 +37,8 @@ def distance_tasks_onehot_ref(db, queries, task_ids, task_slot,
     """Oracle for the original matmul+one-hot distance stage.
 
     Computes the full (T, R) task-by-slot Gram matrix then one-hot-selects
-    the owning column — O(T·R·d) work, kept as the numerical oracle for the
-    ``matmul_onehot`` kernel path (the slot-gather path must agree to 1e-4).
+    the owning column — O(T·R·d) work; the engine's ``matmul_onehot`` mode
+    runs it on the jnp path (the slot-gather kernel must agree to 1e-4).
     """
     valid = task_ids >= 0
     ids = jnp.maximum(task_ids, 0)

@@ -4,43 +4,60 @@ through the continuous-batching pool, PD-disaggregated at the process level
 (prefill engine and decode engine are separate objects exchanging KV
 caches, the vector pool serves both through the two-queue scheduler).
 
-``python -m repro.launch.serve --arch internvl2-1b --requests 8``
+``python -m repro.launch.serve --arch internvl2-1b --requests 8`` runs the
+small smoke config (CPU-sized); ``--full --prompt-len 384`` serves the
+published config at full width with a full-width pool (a chip's job; the
+prompt holds one image's 256 patch positions).
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config, list_archs
+from repro.configs import get_config, get_smoke_config, list_archs
 from repro.configs.base import VectorPoolConfig
 from repro.core.scheduler import VectorRequest
 from repro.core.trinity_pool import VectorPool
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.vector.dataset import make_dataset
 from repro.vector.graph import make_cagra_graph
 
 
+# the full-width pool: ``VectorPoolConfig`` engine widths (R=64 slots,
+# task_batch 2048, top_m 32) at SIFT width, over the largest corpus the host
+# builds an exact kNN graph for (``vector/graph.py``)
+FULL_POOL = VectorPoolConfig(num_vectors=20_000, dim=128)
+
+
 class RealServer:
     """Prefill pool + decode pool + Trinity vector pool, real compute."""
 
-    def __init__(self, cfg, pool_cfg, *, rag_interval: int = 8, seed: int = 0):
+    def __init__(self, cfg, pool_cfg, *, rag_interval: int = 8, seed: int = 0,
+                 pool: Optional[VectorPool] = None):
+        """``pool``: serve from an already built pool (its config must be
+        ``pool_cfg``); None builds one over a synthetic corpus."""
         self.cfg = cfg
         self.params = model_zoo.init_params(cfg, jax.random.PRNGKey(seed))
-        db, _ = make_dataset(pool_cfg.num_vectors, pool_cfg.dim,
-                             num_queries=1, seed=seed)
-        graph = make_cagra_graph(db, pool_cfg.graph_degree, seed=seed)
-        self.pool = VectorPool(pool_cfg, db, graph, policy="trinity")
+        if pool is None:
+            db, _ = make_dataset(pool_cfg.num_vectors, pool_cfg.dim,
+                                 num_queries=1, seed=seed)
+            graph = make_cagra_graph(db, pool_cfg.graph_degree, seed=seed)
+            pool = VectorPool(pool_cfg, db, graph, policy="trinity")
+        self.pool = pool
         self.rag_interval = rag_interval
         self.pool_cfg = pool_cfg
         self._prefill = jax.jit(
             lambda p, b: model_zoo.prefill_fn(cfg, p, b))
         self._decode = jax.jit(
             lambda p, tok, c, n: model_zoo.decode_fn(cfg, p, tok, c, n))
-        self._clock = 0.0
+        # pool sim-time: a pool that already served starts where it stands
+        self._clock = max(r.clock for r in self.pool.replicas)
         self._rid = 0
 
     def _retrieve(self, kind: str, qvec) -> np.ndarray:
@@ -59,8 +76,13 @@ class RealServer:
 
     def generate(self, prompts: np.ndarray, max_new: int = 16):
         """prompts: (B, S) int32. Greedy decode with periodic RAG probes.
-        Returns (tokens (B, max_new), stats)."""
+        With a frontend, its embeddings take the first ``frontend_tokens``
+        positions, so S must hold them. Returns (tokens (B, max_new), stats)."""
         B, S = prompts.shape
+        if not model_zoo.is_encdec(self.cfg) and S < self.cfg.frontend_tokens:
+            raise ValueError(f"{self.cfg.name}: a prompt of {S} tokens cannot "
+                             f"hold the frontend's {self.cfg.frontend_tokens} "
+                             "embedding positions")
         t0 = time.time()
         # prefill-side RAG: one retrieval per request (context injection)
         rng = np.random.default_rng(0)
@@ -76,7 +98,9 @@ class RealServer:
             batch["frontend"] = jnp.ones(
                 (B, self.cfg.frontend_tokens, self.cfg.d_model), jnp.float32)
         logits, _ = self._prefill(self.params, batch)
+        logits.block_until_ready()
         ttft = time.time() - t0
+        finite = jnp.all(jnp.isfinite(logits))
 
         # decode pool consumes the transferred caches (fresh max-len caches
         # seeded by re-running prefill into them token-by-token is wasteful;
@@ -99,10 +123,12 @@ class RealServer:
                 stalls += 1
             lg, caches = self._decode(self.params, tok, caches,
                                       jnp.int32(S + step))
+            finite &= jnp.all(jnp.isfinite(lg))
             tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
             out.append(np.asarray(tok)[:, 0])
         toks = np.stack(out, axis=1)
         return toks, {"ttft_s": ttft, "decode_s": time.time() - t0 - ttft,
+                      "logits_finite": bool(finite),
                       "rag_probes": len(self.pool.metrics.completed),
                       "rag_p95_ms": self.pool.metrics.p(95) * 1e3,
                       "stalls": stalls}
@@ -114,12 +140,20 @@ def main():
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--full", action="store_true",
+                    help="published config and a full-width pool (chip)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_smoke_config(args.arch)
-    pool_cfg = VectorPoolConfig(num_vectors=2000, dim=64, max_requests=16,
-                                top_m=16, task_batch=512, visited_slots=256,
-                                top_k=5)
+    if args.full:
+        cfg = get_config(args.arch)
+        pool_cfg = FULL_POOL
+    else:
+        cfg = get_smoke_config(args.arch)
+        pool_cfg = VectorPoolConfig(num_vectors=2000, dim=64,
+                                    max_requests=16, top_m=16,
+                                    task_batch=512, visited_slots=256,
+                                    top_k=5)
     server = RealServer(cfg, pool_cfg)
     rng = np.random.default_rng(0)
     prompts = rng.integers(

@@ -56,7 +56,7 @@ class ClusterSim:
                  hw: Hardware = V5E, poll_dt: float = 2e-4,
                  straggler_factor: float = 2.5, elastic_decode: bool = False,
                  autoscaler: Optional[AutoscalerConfig] = None,
-                 use_pallas: Optional[bool] = False, seed: int = 0):
+                 use_pallas: Optional[bool] = None, seed: int = 0):
         self.cfg = model_cfg
         self.pool_cfg = pool_cfg
         self.hw = hw

@@ -5,8 +5,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models import model_zoo
 from repro.distributed import sharding as shard
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model_axis=4)  # (2, 4) data x model, Auto axes
 for arch in ("phi3-medium-14b", "deepseek-v3-671b"):
     cfg = get_smoke_config(arch)
     params = model_zoo.init_params(cfg, jax.random.PRNGKey(0))

@@ -189,16 +189,19 @@ def test_fused_multi_step_matches_sequential_steps(setup):
 
 
 def test_distance_modes_agree_through_engine(setup):
-    """The slot-gather Pallas path and the matmul-onehot oracle path must
-    yield equivalent search results end-to-end. The two formulas only
-    agree to ~1e-4 in float32, so a distance tie at a selection boundary
-    may legitimately swap ids — compare with tolerance, not bit-equality."""
+    """The slot-gather Pallas path and the matmul-onehot oracle path (jnp
+    only: the one-hot form has no kernel) must yield equivalent search
+    results end-to-end. The two formulas only agree to ~1e-4 in float32,
+    so a distance tie at a selection boundary may legitimately swap ids —
+    compare with tolerance, not bit-equality."""
     cfg, db, graph, queries, _ = setup
     import dataclasses
     cfg_oh = dataclasses.replace(cfg, distance_mode="matmul_onehot")
     e_sg = ContinuousBatchingEngine(cfg, db, graph, use_pallas=True, seed=11)
-    e_oh = ContinuousBatchingEngine(cfg_oh, db, graph, use_pallas=True,
+    e_oh = ContinuousBatchingEngine(cfg_oh, db, graph, use_pallas=False,
                                     seed=11)
+    with pytest.raises(ValueError, match="no Pallas kernel"):
+        ContinuousBatchingEngine(cfg_oh, db, graph, use_pallas=True).step()
     for i in range(6):
         e_sg.admit(i, queries[i])
         e_oh.admit(i, queries[i])

@@ -102,6 +102,21 @@ def test_megabatch_bit_identical_plain(setup):
     _assert_same(a, b)
 
 
+def test_megabatch_pallas_path_matches_jnp_path(setup):
+    """The vmapped megabatch step on the distance kernel (the path a TPU
+    selects by itself) returns what the jnp oracle path returns."""
+    db, queries = setup
+    a = _drive(ShardedVectorPool(_cfg(**ALL_ON), db, seed=0,
+                                 use_pallas=False), queries)
+    b = _drive(ShardedVectorPool(_cfg(**ALL_ON), db, seed=0,
+                                 use_pallas=True), queries)
+    assert set(a) == set(b), (len(a), len(b))
+    for rid in a:
+        np.testing.assert_array_equal(a[rid][0], b[rid][0], err_msg=str(rid))
+        np.testing.assert_allclose(a[rid][1], b[rid][1], rtol=1e-5,
+                                   atol=1e-5, err_msg=str(rid))
+
+
 def test_megabatch_bit_identical_with_quiesced_inserts(setup):
     """Inserts mutate the searched corpus, so a probe's results depend on
     WHEN the broadcast lands relative to its chunks — and changing that

@@ -97,10 +97,11 @@ def test_small_scale_dryrun_subprocess(tmp_path):
         from repro.distributed import sharding as shard
         from repro.launch import hlo_cost
         from repro.launch.dryrun import build_step
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke_config("deepseek-moe-16b")
         shape = dataclasses.replace(TRAIN_4K, seq_len=64, global_batch=8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(model_axis=4)  # (2, 4), Auto axes
         fn, args, in_sh = build_step(cfg, shape, mesh)
         with mesh, shard.activation_sharding(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()

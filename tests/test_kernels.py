@@ -19,6 +19,12 @@ def _rand(shape, dtype, k):
 # ---------------------------------------------------------------------------
 
 
+# the kernel's oracle for each engine distance mode: the Pallas kernel is
+# the slot-gather form; the matmul+one-hot form survives as a jnp oracle
+ORACLES = {"slot_gather": ref.distance_tasks_ref,
+           "matmul_onehot": ref.distance_tasks_onehot_ref}
+
+
 @pytest.mark.parametrize("mode", ["slot_gather", "matmul_onehot"])
 @pytest.mark.parametrize("metric", ["l2", "ip"])
 @pytest.mark.parametrize("N,d,R,T", [
@@ -30,49 +36,69 @@ def test_distance_tasks_matches_oracle(mode, metric, N, d, R, T):
     task_ids = jax.random.randint(jax.random.fold_in(KEY, 3), (T,), 0, N)
     task_ids = task_ids.at[::5].set(-1)  # masked dummies
     task_slot = jax.random.randint(jax.random.fold_in(KEY, 4), (T,), 0, R)
-    out = ops.distance_tasks(db, queries, task_ids, task_slot, metric=metric,
-                             mode=mode)
-    want = ref.distance_tasks_ref(db, queries, task_ids, task_slot, metric=metric)
+    out = ops.distance_tasks(ops.corpus_layout(db), queries, task_ids,
+                             task_slot, metric=metric)
+    want = ORACLES[mode](db, queries, task_ids, task_slot, metric=metric)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("metric", ["l2", "ip"])
 def test_slot_gather_matches_matmul_onehot_oracle(metric):
-    """Acceptance: the O(T·d) slot-gather path agrees with the O(T·R·d)
-    matmul+one-hot oracle (both kernel and jnp forms) to 1e-4."""
+    """Acceptance: the O(T·d) slot-gather kernel agrees with the O(T·R·d)
+    matmul+one-hot oracle to 1e-4 (d=96 also exercises lane padding)."""
     N, d, R, T = 800, 96, 12, 512
     db = _rand((N, d), jnp.float32, 40)
     queries = _rand((R, d), jnp.float32, 41)
     task_ids = jax.random.randint(jax.random.fold_in(KEY, 42), (T,), 0, N)
     task_ids = task_ids.at[::7].set(-1)
     task_slot = jax.random.randint(jax.random.fold_in(KEY, 43), (T,), 0, R)
-    gather = ops.distance_tasks(db, queries, task_ids, task_slot,
-                                metric=metric, mode="slot_gather")
-    onehot_kernel = ops.distance_tasks(db, queries, task_ids, task_slot,
-                                       metric=metric, mode="matmul_onehot")
+    gather = ops.distance_tasks(ops.corpus_layout(db), queries, task_ids,
+                                task_slot, metric=metric)
     onehot_oracle = ref.distance_tasks_onehot_ref(db, queries, task_ids,
                                                   task_slot, metric=metric)
     np.testing.assert_allclose(np.asarray(gather), np.asarray(onehot_oracle),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(gather), np.asarray(onehot_kernel),
                                rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("mode", ["slot_gather", "matmul_onehot"])
 def test_distance_tasks_dummy_padding_invariant(mode):
     """Appending masked dummies never changes real task results (paper:
-    'round up with masked dummies to preserve a stable operator shape')."""
+    'round up with masked dummies to preserve a stable operator shape') —
+    for the kernel and for the one-hot oracle the jnp path runs."""
     db = _rand((300, 64), jnp.float32, 5)
     queries = _rand((8, 64), jnp.float32, 6)
+    if mode == "slot_gather":
+        corpus = ops.corpus_layout(db)
+        fn = lambda i, s: ops.distance_tasks(corpus, queries, i, s)
+    else:
+        fn = lambda i, s: ref.distance_tasks_onehot_ref(db, queries, i, s)
     ids = jax.random.randint(jax.random.fold_in(KEY, 7), (256,), 0, 300)
     slot = jax.random.randint(jax.random.fold_in(KEY, 8), (256,), 0, 8)
-    base = ops.distance_tasks(db, queries, ids, slot, mode=mode)
+    base = fn(ids, slot)
     padded_ids = jnp.concatenate([ids, jnp.full((256,), -1, jnp.int32)])
     padded_slot = jnp.concatenate([slot, jnp.zeros((256,), jnp.int32)])
-    padded = ops.distance_tasks(db, queries, padded_ids, padded_slot, mode=mode)
+    padded = fn(padded_ids, padded_slot)
     np.testing.assert_allclose(np.asarray(base), np.asarray(padded[:256]),
                                rtol=1e-6)
+
+
+def test_distance_tasks_rejects_unplaced_corpus():
+    """The kernel never re-lays-out the corpus itself: a raw (N, d) corpus
+    is refused instead of being copied on every call."""
+    db = _rand((300, 128), jnp.float32, 9)
+    ids = jnp.zeros((256,), jnp.int32)
+    with pytest.raises(ValueError, match="corpus_layout"):
+        ops.distance_tasks(db, db[:8], ids, ids)
+
+
+def test_corpus_layout_pads_lanes_with_zeros():
+    db = _rand((10, 96), jnp.float32, 11)
+    corpus = ops.corpus_layout(db)
+    assert corpus.shape == (10, 1, 128) and corpus.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(corpus[:, 0, :96]),
+                                  np.asarray(db))
+    assert not np.asarray(corpus[:, 0, 96:]).any()
 
 
 # ---------------------------------------------------------------------------

@@ -34,3 +34,16 @@ def test_generation_is_deterministic(server):
     t1, _ = server.generate(prompts, max_new=6)
     t2, _ = server.generate(prompts, max_new=6)
     np.testing.assert_array_equal(t1, t2)
+
+
+def test_prompt_must_hold_frontend_positions():
+    """A frontend's embeddings take the first ``frontend_tokens`` positions;
+    a shorter prompt is refused with a clear error."""
+    cfg = get_smoke_config("internvl2-1b")
+    pool_cfg = VectorPoolConfig(num_vectors=300, dim=64, max_requests=16,
+                                top_m=16, task_batch=512, visited_slots=256,
+                                top_k=5)
+    srv = RealServer(cfg, pool_cfg)
+    short = np.zeros((1, cfg.frontend_tokens - 1), np.int32)
+    with pytest.raises(ValueError, match="frontend"):
+        srv.generate(short, max_new=1)
