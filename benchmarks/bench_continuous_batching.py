@@ -11,9 +11,8 @@ is a test); what differs is execution:
   continuous — Trinity §3.2: finished requests vacate slots immediately,
   newcomers join the next extend's distance batch.
 
-Reported: P50/P95 latency, mean task-slot occupancy (the GPU-utilisation
-proxy: fraction of the fixed-shape distance operator doing real work), and
-sustained throughput, across offered loads.
+Reported: P50/P95 latency and sustained throughput, across offered
+loads.
 """
 from __future__ import annotations
 
@@ -34,7 +33,6 @@ def per_request_batched(cfg, db, graph, queries, arrivals, batch_size: int,
     """Baseline executor: window the stream, lockstep-search each window."""
     t_ext = rm.extend_time(cfg)
     lat = np.zeros(len(arrivals))
-    occupancy = []
     throughput_end = 0.0
     i = 0
     t = 0.0
@@ -47,19 +45,17 @@ def per_request_batched(cfg, db, graph, queries, arrivals, batch_size: int,
             j += 1
         start = max(t, arrivals[j - 1])
         q = jnp.asarray(queries[i:j])
-        _, _, extends, iters = search_batch(
+        _, _, _, iters = search_batch(
             dbj, gj, q, top_m=cfg.top_m, p=cfg.parents_per_step,
             max_iters=64, num_entries=16, visited_slots=cfg.visited_slots)
         iters = int(iters)
-        ext = np.asarray(extends)
         # every iteration launches a full fixed-shape batch; stragglers
         # keep the whole launch alive
         t = start + iters * t_ext
         lat[i:j] = t - arrivals[i:j]
-        occupancy.append(ext.sum() / max(iters * batch_size, 1))
         throughput_end = t
         i = j
-    return lat, float(np.mean(occupancy)), len(arrivals) / throughput_end
+    return lat, len(arrivals) / throughput_end
 
 
 def continuous(cfg, db, graph, queries, arrivals):
@@ -71,8 +67,7 @@ def continuous(cfg, db, graph, queries, arrivals):
     m = pool.metrics
     lat = m.latencies()
     done_t = max(r.t_completed for r in m.completed)
-    live = pool.replicas[0].engine.slot_liveness
-    return lat, live, len(m.completed) / done_t
+    return lat, len(m.completed) / done_t
 
 
 def run(emit_rows: bool = True, n_requests: int = 256):
@@ -90,22 +85,19 @@ def run(emit_rows: bool = True, n_requests: int = 256):
     for frac in (0.1, 0.5, 1.5):
         qps = frac * capacity
         arr = poisson_arrivals(qps, n_requests, seed=3)
-        lat_b, live_b, thr_b = per_request_batched(
+        lat_b, thr_b = per_request_batched(
             cfg, db, graph, qs, arr, batch_size=cfg.max_requests,
             flush_s=2e-3)
-        lat_c, live_c, thr_c = continuous(cfg, db, graph, qs, arr)
-        for name, lat, live, thr in (
-                ("per_request", lat_b, live_b, thr_b),
-                ("continuous", lat_c, live_c, thr_c)):
+        lat_c, thr_c = continuous(cfg, db, graph, qs, arr)
+        for name, lat, thr in (("per_request", lat_b, thr_b),
+                               ("continuous", lat_c, thr_c)):
             rows += [
                 (name, frac, "p50_ms", round(np.percentile(lat, 50) * 1e3, 4)),
                 (name, frac, "p95_ms", round(np.percentile(lat, 95) * 1e3, 4)),
-                (name, frac, "slot_liveness", round(live, 4)),
                 (name, frac, "throughput_qps", round(thr, 1)),
             ]
         out[frac] = {"p95_speedup": np.percentile(lat_b, 95)
-                     / max(np.percentile(lat_c, 95), 1e-12),
-                     "liveness_gain": live_c / max(live_b, 1e-12)}
+                     / max(np.percentile(lat_c, 95), 1e-12)}
     if emit_rows:
         emit(rows, ("engine", "load_frac", "metric", "value"))
     return out

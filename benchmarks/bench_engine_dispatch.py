@@ -53,9 +53,9 @@ def _drain_legacy(engine, queries, n):
         engine.admit(i, queries[i])
     steps = 0
     while int(jnp.sum(engine.state.active)):
-        # the seed engine's step() opened with
-        # `total_live_slots += int(jnp.sum(active))` — a second device
-        # reduction+sync per extend
+        # the seed engine's step() opened with a live-slot count,
+        # `int(jnp.sum(active))` — a second device reduction+sync per
+        # extend
         _ = int(jnp.sum(engine.state.active))
         engine.state, completed, tasks = extend_step(
             engine.state, engine.db, engine.graph,
@@ -77,16 +77,20 @@ def _drain_per_step(engine, queries, n):
     """Per-step dispatch with the host-side bookkeeping fixes only (batched
     admission, no device active-count poll) — isolates the scan fusion."""
     engine.admit_batch([(i, queries[i]) for i in range(n)])
+    steps = 0
     while engine.num_active:
         engine.step()
-    return engine.steps
+        steps += 1
+    return steps
 
 
 def _drain_fused(engine, queries, n, k):
     engine.admit_batch([(i, queries[i]) for i in range(n)])
+    steps = 0
     while engine.num_active:
         engine.step_multi(k)
-    return engine.steps
+        steps += k
+    return steps
 
 
 def bench_stepping(cfg, db, graph, queries, chunks=(4, 8), rounds: int = 7):

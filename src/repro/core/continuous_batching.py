@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref as kernel_ref
 from repro.vector.cagra import INF, _hash_probe, _merge_topm
@@ -346,47 +347,55 @@ def _extend_impl(state: EngineState, db, graph, *, p: int, task_batch: int,
     Returns (new_state, completed (R,) bool, tasks_emitted scalar)."""
     R, M = state.top_ids.shape
     D = graph.shape[1]
-    task_ids, task_slot, expanded, visited, parent_ok = _build_tasks(
-        state, graph, p)
+    with jax.named_scope("build_tasks"):
+        task_ids, task_slot, expanded, visited, parent_ok = _build_tasks(
+            state, graph, p)
 
     n_emit = task_ids.shape[0]
     assert n_emit <= task_batch, (n_emit, task_batch)
     pad = task_batch - n_emit
-    task_ids_p = jnp.concatenate([task_ids, jnp.full((pad,), -1, jnp.int32)])
-    task_slot_p = jnp.concatenate([task_slot, jnp.zeros((pad,), jnp.int32)])
+    with jax.named_scope("pad_tasks"):
+        task_ids_p = jnp.concatenate([task_ids,
+                                      jnp.full((pad,), -1, jnp.int32)])
+        task_slot_p = jnp.concatenate([task_slot,
+                                       jnp.zeros((pad,), jnp.int32)])
 
     # ---- stage 4: ONE fixed-shape distance operator ----------------------
-    if use_pallas:
-        if distance_mode != "slot_gather":
-            raise ValueError(f"distance_mode {distance_mode!r} has no Pallas "
-                             "kernel; it runs on the jnp path only "
-                             "(use_pallas=False)")
-        dists = kernel_ops.distance_tasks(db, state.query_vecs, task_ids_p,
-                                          task_slot_p, metric=metric)
-    elif distance_mode == "matmul_onehot":
-        dists = kernel_ref.distance_tasks_onehot_ref(
-            db, state.query_vecs, task_ids_p, task_slot_p, metric=metric)
-    elif distance_mode == "slot_gather":
-        dists = kernel_ref.distance_tasks_ref(db, state.query_vecs, task_ids_p,
-                                              task_slot_p, metric=metric)
-    else:
-        raise ValueError(f"unknown distance mode: {distance_mode!r}")
-    dists = dists[:n_emit].reshape(R, p * D)
+    with jax.named_scope("distance"):
+        if use_pallas:
+            if distance_mode != "slot_gather":
+                raise ValueError(f"distance_mode {distance_mode!r} has no "
+                                 "Pallas kernel; it runs on the jnp path "
+                                 "only (use_pallas=False)")
+            dists = kernel_ops.distance_tasks(db, state.query_vecs,
+                                              task_ids_p, task_slot_p,
+                                              metric=metric)
+        elif distance_mode == "matmul_onehot":
+            dists = kernel_ref.distance_tasks_onehot_ref(
+                db, state.query_vecs, task_ids_p, task_slot_p, metric=metric)
+        elif distance_mode == "slot_gather":
+            dists = kernel_ref.distance_tasks_ref(
+                db, state.query_vecs, task_ids_p, task_slot_p, metric=metric)
+        else:
+            raise ValueError(f"unknown distance mode: {distance_mode!r}")
+        dists = dists[:n_emit].reshape(R, p * D)
     cand_ids = task_ids.reshape(R, p * D)
 
     # ---- stage 5: scatter back + per-slot topM merge ---------------------
-    top_ids, top_dists, expanded = jax.vmap(_merge_topm)(
-        state.top_ids, state.top_dists, expanded, cand_ids, dists)
+    with jax.named_scope("merge_topm"):
+        top_ids, top_dists, expanded = jax.vmap(_merge_topm)(
+            state.top_ids, state.top_dists, expanded, cand_ids, dists)
 
     # ---- stage 6: convergence = no parent was expandable, OR the slot's
     # extend budget is exhausted (forced completion: the budgeted extend
     # still runs and merges before the slot exits) ---------------------------
-    did_work = jnp.any(parent_ok, axis=1)
-    extends = state.extends + jnp.where(state.active & did_work, 1, 0)
-    over_budget = (state.budget > 0) & (extends >= state.budget)
-    completed = state.active & (~did_work | over_budget)
-    new_active = state.active & did_work & ~over_budget
-    tasks_emitted = jnp.sum(task_ids >= 0)
+    with jax.named_scope("converge"):
+        did_work = jnp.any(parent_ok, axis=1)
+        extends = state.extends + jnp.where(state.active & did_work, 1, 0)
+        over_budget = (state.budget > 0) & (extends >= state.budget)
+        completed = state.active & (~did_work | over_budget)
+        new_active = state.active & did_work & ~over_budget
+        tasks_emitted = jnp.sum(task_ids >= 0)
 
     new_state = EngineState(state.query_vecs, top_ids, top_dists, expanded,
                             visited, new_active, extends, state.budget)
@@ -471,11 +480,7 @@ class ContinuousBatchingEngine:
         self.distance_mode = cfg.distance_mode
         self.extend_chunk = max(1, cfg.extend_chunk)
         self._key = jax.random.PRNGKey(seed)
-        # metrics
-        self.total_tasks = 0
-        self.total_capacity = 0
-        self.total_live_slots = 0
-        self.steps = 0
+        self.total_tasks = 0  # distance tasks emitted, summed over steps
 
     @property
     def num_active(self) -> int:
@@ -657,39 +662,31 @@ class ContinuousBatchingEngine:
         ``substep`` ∈ [0, K) the extend at which the request converged (for
         exact completion-time attribution in the pool)."""
         k = self.extend_chunk if num_steps is None else num_steps
-        live = self.num_active
-        self.state, completed_k, tasks_k = extend_multi(
-            self.state, self.db, self.graph, num_steps=k,
-            p=self.cfg.parents_per_step, task_batch=self.cfg.task_batch,
-            use_pallas=self.use_pallas, metric=self.cfg.metric,
-            distance_mode=self.distance_mode)
+        with tracing.span("dispatch", active=self.num_active):
+            self.state, completed_k, tasks_k = extend_multi(
+                self.state, self.db, self.graph, num_steps=k,
+                p=self.cfg.parents_per_step, task_batch=self.cfg.task_batch,
+                use_pallas=self.use_pallas, metric=self.cfg.metric,
+                distance_mode=self.distance_mode)
         # the ONE host-device sync for this dispatch
-        completed_k, tasks_k = jax.device_get((completed_k, tasks_k))
-        self.total_tasks += int(tasks_k.sum())
-        self.total_capacity += k * self.cfg.task_batch
-        self.steps += k
-        # per-substep live-slot accounting, derived host-side: completions
-        # are the only active→inactive transitions and no admissions happen
-        # mid-chunk
-        per_step_completions = completed_k.sum(axis=1)
-        for i in range(k):
-            self.total_live_slots += live
-            live -= int(per_step_completions[i])
-
-        out = []
-        if completed_k.any():
-            top_ids = np.asarray(self.state.top_ids)
-            top_dists = np.asarray(self.state.top_dists)
-            extends = np.asarray(self.state.extends)
-            for i in range(k):
-                for slot in np.nonzero(completed_k[i])[0]:
-                    rid = self.slot_request.pop(int(slot))
-                    # per-slot top-k truncation (retrieval-class heterogeneity)
-                    kk = self.slot_topk.pop(int(slot), self.cfg.top_k)
-                    out.append((rid, top_ids[slot, :kk].copy(),
-                                top_dists[slot, :kk].copy(),
-                                int(extends[slot]), i))
-                    self.free_slots.append(int(slot))
+        with tracing.span("sync"):
+            completed_k, tasks_k = jax.device_get((completed_k, tasks_k))
+        with tracing.span("collect", done=int(completed_k.sum())):
+            self.total_tasks += int(tasks_k.sum())
+            out = []
+            if completed_k.any():
+                top_ids = np.asarray(self.state.top_ids)
+                top_dists = np.asarray(self.state.top_dists)
+                extends = np.asarray(self.state.extends)
+                for i in range(k):
+                    for slot in np.nonzero(completed_k[i])[0]:
+                        rid = self.slot_request.pop(int(slot))
+                        # per-slot top-k truncation (retrieval classes)
+                        kk = self.slot_topk.pop(int(slot), self.cfg.top_k)
+                        out.append((rid, top_ids[slot, :kk].copy(),
+                                    top_dists[slot, :kk].copy(),
+                                    int(extends[slot]), i))
+                        self.free_slots.append(int(slot))
         return out, tasks_k
 
     def step(self) -> Tuple[List[Tuple[int, np.ndarray, np.ndarray, int]], int]:
@@ -718,17 +715,6 @@ class ContinuousBatchingEngine:
             done.extend((rid, ids, dists, ext) for rid, ids, dists, ext, _ in c)
             steps += chunk
         return done
-
-    @property
-    def slot_occupancy(self) -> float:
-        """Fraction of the fixed-shape distance kernel doing real work."""
-        return self.total_tasks / max(self.total_capacity, 1)
-
-    @property
-    def slot_liveness(self) -> float:
-        """Mean fraction of request slots active per launch (comparable to
-        the lockstep baseline's live-query fraction)."""
-        return self.total_live_slots / max(self.steps * self.cfg.max_requests, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -1073,15 +1059,9 @@ class GroupEngine:
         """K fused extend steps for the cohort ``lanes`` — ONE dispatch,
         one mask sync. Returns host (completed (K, G, R), tasks (K, G));
         lanes outside the cohort are frozen bit-wise."""
-        mask = np.zeros((self.g_cap,), bool)
-        mask[lanes] = True
-        cfgv = self.cfg
-        self.state, completed_k, tasks_k = extend_multi_group(
-            self.state, self.dbs, self.graphs, jnp.asarray(mask),
-            num_steps=num_steps, p=cfgv.parents_per_step,
-            task_batch=cfgv.task_batch, use_pallas=self.use_pallas,
-            metric=cfgv.metric, distance_mode=cfgv.distance_mode)
-        return jax.device_get((completed_k, tasks_k))
+        pending = self.step_lanes_async(lanes, num_steps)
+        with tracing.span("sync"):
+            return jax.device_get(pending)
 
     def step_lanes_async(self, lanes: List[int], num_steps: int):
         """Double-buffered variant: dispatch the cohort chunk and return
@@ -1090,11 +1070,12 @@ class GroupEngine:
         mask = np.zeros((self.g_cap,), bool)
         mask[lanes] = True
         cfgv = self.cfg
-        self.state, completed_k, tasks_k = extend_multi_group(
-            self.state, self.dbs, self.graphs, jnp.asarray(mask),
-            num_steps=num_steps, p=cfgv.parents_per_step,
-            task_batch=cfgv.task_batch, use_pallas=self.use_pallas,
-            metric=cfgv.metric, distance_mode=cfgv.distance_mode)
+        with tracing.span("dispatch", lanes=len(lanes)):
+            self.state, completed_k, tasks_k = extend_multi_group(
+                self.state, self.dbs, self.graphs, jnp.asarray(mask),
+                num_steps=num_steps, p=cfgv.parents_per_step,
+                task_batch=cfgv.task_batch, use_pallas=self.use_pallas,
+                metric=cfgv.metric, distance_mode=cfgv.distance_mode)
         return completed_k, tasks_k
 
     def collect_rows(self, entries):
@@ -1145,9 +1126,6 @@ class GroupMember(ContinuousBatchingEngine):
         self.extend_chunk = max(1, group.cfg.extend_chunk)
         self._key = jax.random.PRNGKey(seed)
         self.total_tasks = 0
-        self.total_capacity = 0
-        self.total_live_slots = 0
-        self.steps = 0
 
     # ------------------------------------------------------- admission
     def stage_admit_batch(self, requests) -> dict:
@@ -1293,16 +1271,10 @@ class GroupMember(ContinuousBatchingEngine):
 
     def step_multi(self, num_steps: Optional[int] = None):
         k = self.extend_chunk if num_steps is None else num_steps
-        live = self.num_active
         completed_k, tasks_k = self.group.step_lanes([self.lane], k)
-        ck = completed_k[:, self.lane]
-        tk = np.ascontiguousarray(tasks_k[:, self.lane])
-        self.total_tasks += int(tk.sum())
-        self.total_capacity += k * self.cfg.task_batch
-        self.steps += k
-        per_step_completions = ck.sum(axis=1)
-        for i in range(k):
-            self.total_live_slots += live
-            live -= int(per_step_completions[i])
-        out = self.collect_completions(ck) if ck.any() else []
+        with tracing.span("collect"):
+            ck = completed_k[:, self.lane]
+            tk = np.ascontiguousarray(tasks_k[:, self.lane])
+            self.total_tasks += int(tk.sum())
+            out = self.collect_completions(ck) if ck.any() else []
         return out, tk

@@ -145,6 +145,7 @@ class VectorRequest:
     shard: Optional[int] = dataclasses.field(default=None, repr=False)
     # stage-aware preemption bookkeeping
     preemptions: int = 0  # times evicted so far (capped by max_preemptions)
+    admissions: int = 0  # times seated in an engine slot
     checkpoint: Optional[object] = None  # engine SlotCheckpoint while queued
     extends_done: int = 0  # extends already executed (stamped at eviction)
     t_preempted: Optional[float] = None
@@ -292,10 +293,14 @@ class LaneScheduler:
     engine from the EDF, FIFO and background lanes."""
 
     def __init__(self, cfg, policy: str = "trinity",
-                 classes: Optional[Dict[str, RetrievalClass]] = None):
+                 classes: Optional[Dict[str, RetrievalClass]] = None,
+                 metrics=None):
         assert policy in ("trinity", "prefill_first", "decode_first",
                           "fifo_shared")
         self.cfg = cfg
+        # the pool's PoolMetrics: first admissions add to its queue-wait
+        # counters (None: nothing counted)
+        self.metrics = metrics
         self.policy = policy
         self.classes = dict(classes) if classes is not None \
             else build_registry(cfg)
@@ -408,6 +413,10 @@ class LaneScheduler:
             if req.t_preempted is not None:
                 req.resume_wait += t_now - req.t_preempted
                 req.t_preempted = None
+            elif req.admissions == 0 and self.metrics is not None:
+                self.metrics.queue_wait_s[req.kind] += t_now - req.t_arrival
+                self.metrics.admitted[req.kind] += 1
+            req.admissions += 1
             req.t_admitted = t_now
 
     # -- stage-aware preemption (paper contribution 3) ----------------------

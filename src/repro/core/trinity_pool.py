@@ -67,13 +67,14 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import math
+from collections import defaultdict
 from typing import Dict, List, Optional, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import roofline_model
+from repro.core import roofline_model, tracing
 from repro.core.continuous_batching import (ContinuousBatchingEngine,
                                             GroupEngine, SlotCheckpoint,
                                             SlotParams, _pow2_pad,
@@ -98,6 +99,13 @@ class PoolMetrics:
     preemptions: int = 0  # slot evictions
     resumes: int = 0  # checkpointed requests re-seated
     preempt_time: float = 0.0  # total evicted time across completed reqs
+    # scheduler queues, by retrieval class (stage): the summed wait
+    # t_admitted − t_arrival of each request's first admission, and the
+    # number of first admissions (re-admissions go to preempt_time)
+    queue_wait_s: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: defaultdict(float))
+    admitted: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: defaultdict(int))
     # online index growth
     inserts: int = 0  # cache-segment nodes added
     cache_evictions: int = 0  # cache entries retired (TTL / capacity cap)
@@ -270,7 +278,8 @@ class VectorPool:
             max_rows=cfg.replica_max_rows)
         self._check_capacity(self.index)
         self.scheduler = TwoQueueScheduler(cfg, policy=policy,
-                                           classes=classes)
+                                           classes=classes,
+                                           metrics=self.metrics)
         self.schedulers = [self.scheduler]
         self.replicas: List[_Replica] = [
             _Replica(i, cfg, self.index, self._use_pallas, self._seed + i)
@@ -391,13 +400,14 @@ class VectorPool:
     def run_until(self, t_end: float):
         """Advance every replica's clock to t_end, stepping engines whenever
         the scheduler decides to flush admissions or work is active."""
-        while True:
-            rep = min((r for r in self.replicas), key=lambda r: r.clock)
-            if rep.clock >= t_end:
-                break
-            self._release_pending(rep.clock)
-            self._step_replica(rep, t_end)
-        self._maybe_scale(t_end)
+        with tracing.span("run_until"):
+            while True:
+                rep = min((r for r in self.replicas), key=lambda r: r.clock)
+                if rep.clock >= t_end:
+                    break
+                self._release_pending(rep.clock)
+                self._step_replica(rep, t_end)
+            self._maybe_scale(t_end)
 
     def kill_replica(self, idx: int):
         """Fail-stop: the replica's device state is gone. Each in-flight
@@ -581,16 +591,20 @@ class VectorPool:
         ``resume_batch`` scatter (bit-identical resume)."""
         fresh = [r for r in batch if r.checkpoint is None]
         resumed = [r for r in batch if r.checkpoint is not None]
-        if fresh:
-            rep.engine.admit_batch([(r.rid, r.qvec, self._params_for(r, rep))
-                                    for r in fresh])
-        if resumed:
-            rep.engine.resume_batch([(r.rid, r.checkpoint) for r in resumed])
-            for req in resumed:
-                req.checkpoint = None
-            self.metrics.resumes += len(resumed)
-        for req in batch:
-            rep.in_flight[req.rid] = req
+        with tracing.span("admit", n=len(batch),
+                          **tracing.rids(r.rid for r in batch)):
+            if fresh:
+                rep.engine.admit_batch([(r.rid, r.qvec,
+                                         self._params_for(r, rep))
+                                        for r in fresh])
+            if resumed:
+                rep.engine.resume_batch([(r.rid, r.checkpoint)
+                                         for r in resumed])
+                for req in resumed:
+                    req.checkpoint = None
+                self.metrics.resumes += len(resumed)
+            for req in batch:
+                rep.in_flight[req.rid] = req
 
     def _maybe_rebalance(self, rep: _Replica, t: float):
         """Workload-adaptive rebalancing hook, invoked between fused
@@ -610,13 +624,14 @@ class VectorPool:
         victims = sched.plan_preemption(t, list(rep.in_flight.values()))
         if not victims:
             return
-        for rid, ckpt in rep.engine.preempt([v.rid for v in victims]):
-            req = rep.in_flight.pop(rid)
-            sched.requeue_preempted(req, ckpt, t)
-        self.metrics.preemptions += len(victims)
-        urgent = sched.take_urgent(rep.engine.num_free, t)
-        if urgent:
-            self._admit(rep, urgent)
+        with tracing.span("preempt", n=len(victims)):
+            for rid, ckpt in rep.engine.preempt([v.rid for v in victims]):
+                req = rep.in_flight.pop(rid)
+                sched.requeue_preempted(req, ckpt, t)
+            self.metrics.preemptions += len(victims)
+            urgent = sched.take_urgent(rep.engine.num_free, t)
+            if urgent:
+                self._admit(rep, urgent)
 
     def _on_complete(self, req: VectorRequest, rep: _Replica):
         """Completion hook (request already stamped with results/times)."""
@@ -631,58 +646,63 @@ class VectorPool:
     def _step_replica(self, rep: _Replica, t_end: float):
         t = rep.clock
         sched = self._sched_for(rep)
-        sched.controller.maybe_update(t, self.feedback)
-        self._maybe_scale(t)
+        with tracing.span("schedule", queued=sched.queued()):
+            sched.controller.maybe_update(t, self.feedback)
+            self._maybe_scale(t)
 
-        healthy = self._healthy(rep)
-        self._maybe_hedge(rep, t)
-        if healthy:
-            self._maybe_rebalance(rep, t)
-            self._maybe_preempt(rep, t)
-        free = rep.engine.num_free
-        if healthy and \
-                sched.should_flush(t, free, rep.engine.num_active):
-            batch = sched.select(free, t)
-            if batch:
-                self._admit(rep, batch)
+            healthy = self._healthy(rep)
+            self._maybe_hedge(rep, t)
+            if healthy:
+                self._maybe_rebalance(rep, t)
+                self._maybe_preempt(rep, t)
+            free = rep.engine.num_free
+            if healthy and \
+                    sched.should_flush(t, free, rep.engine.num_active):
+                batch = sched.select(free, t)
+                if batch:
+                    self._admit(rep, batch)
 
-        if rep.engine.num_active == 0:
-            # idle: jump to the next arrival (or a small quantum / t_end)
-            if sched.queued() > 0:
-                rep.clock = t + sched.controller.tau_pre
-            elif self._pending:
-                rep.clock = max(t + 1e-9, min(self._pending[0][0], t_end))
-            else:
-                rep.clock = t_end
-            return
+            if rep.engine.num_active == 0:
+                # idle: jump to the next arrival (or a small quantum / t_end)
+                if sched.queued() > 0:
+                    rep.clock = t + sched.controller.tau_pre
+                elif self._pending:
+                    rep.clock = max(t + 1e-9,
+                                    min(self._pending[0][0], t_end))
+                else:
+                    rep.clock = t_end
+                return
 
         # ONE fused dispatch: K extend steps, one completion-mask sync
         k = rep.engine.extend_chunk
         completions, tasks_k = rep.engine.step_multi(k)
-        dt = roofline_model.extend_time(self.cfg) * rep.slowdown
-        rep.clock = t + k * dt
-        rep.ext_latency_ewma = 0.9 * rep.ext_latency_ewma + 0.1 * dt
-        sched.observe_extend_latency(dt)
-        self.metrics.extend_steps += k
-        self.metrics.tasks_emitted += int(tasks_k.sum())
-        self.metrics.tasks_capacity += k * self.cfg.task_batch
+        with tracing.span("complete", done=len(completions),
+                          **tracing.rids(c[0] for c in completions)):
+            dt = roofline_model.extend_time(self.cfg) * rep.slowdown
+            rep.clock = t + k * dt
+            rep.ext_latency_ewma = 0.9 * rep.ext_latency_ewma + 0.1 * dt
+            sched.observe_extend_latency(dt)
+            self.metrics.extend_steps += k
+            self.metrics.tasks_emitted += int(tasks_k.sum())
+            self.metrics.tasks_capacity += k * self.cfg.task_batch
 
-        for rid, ids, dists, extends, substep in completions:
-            req = rep.in_flight.pop(rid)
-            # attribute completion to its exact sub-step, not the chunk end
-            req.t_completed = t + (substep + 1) * dt
-            req.extends_used = extends
-            req.result_ids = ids
-            req.result_dists = dists
-            self._on_complete(req, rep)
+            for rid, ids, dists, extends, substep in completions:
+                req = rep.in_flight.pop(rid)
+                # attribute completion to its exact sub-step, not chunk end
+                req.t_completed = t + (substep + 1) * dt
+                req.extends_used = extends
+                req.result_ids = ids
+                req.result_dists = dists
+                self._on_complete(req, rep)
 
-        if self.cfg.rescue_enabled:
-            # refresh the death-rescue snapshots: one non-destructive
-            # gather + sync per chunk. A kill can only land between
-            # chunks (nothing else advances slot state), so the snapshot
-            # IS the exact state at any failure before the next chunk
-            rep.snapshots = dict(rep.engine.snapshot(
-                sorted(rep.in_flight))) if rep.in_flight else {}
+            if self.cfg.rescue_enabled:
+                # refresh the death-rescue snapshots: one non-destructive
+                # gather + sync per chunk. A kill can only land between
+                # chunks (nothing else advances slot state), so the
+                # snapshot IS the exact state at any failure before the
+                # next chunk
+                rep.snapshots = dict(rep.engine.snapshot(
+                    sorted(rep.in_flight))) if rep.in_flight else {}
 
     def _maybe_scale(self, t_now: float):
         if not self.elastic:
@@ -785,7 +805,8 @@ class ShardedVectorPool(VectorPool):
             self._check_capacity(sh)
         self.index = None  # no monolithic index exists
         self.schedulers = [TwoQueueScheduler(cfg, policy=policy,
-                                             classes=classes)
+                                             classes=classes,
+                                             metrics=self.metrics)
                            for _ in range(S)]
         self.scheduler = self.schedulers[0]  # primary (class registry)
         for sch in self.schedulers[1:]:
@@ -1176,14 +1197,15 @@ class ShardedVectorPool(VectorPool):
         Knob off: the inherited serial per-replica loop, bit-identical."""
         if not self._mega:
             return super().run_until(t_end)
-        while True:
-            t_min = min(r.clock for r in self.replicas)
-            if t_min >= t_end:
-                break
-            self._release_pending(t_min)
-            cohort = [r for r in self.replicas if r.clock == t_min]
-            self._step_group(cohort, t_end)
-        self._maybe_scale(t_end)
+        with tracing.span("run_until"):
+            while True:
+                t_min = min(r.clock for r in self.replicas)
+                if t_min >= t_end:
+                    break
+                self._release_pending(t_min)
+                cohort = [r for r in self.replicas if r.clock == t_min]
+                self._step_group(cohort, t_end)
+            self._maybe_scale(t_end)
 
     def _step_group(self, cohort: List[_Replica], t_end: float):
         """Advance every frontier replica one fused chunk via grouped
@@ -1196,109 +1218,95 @@ class ShardedVectorPool(VectorPool):
         under double buffering)."""
         t = cohort[0].clock
         cfg = self.cfg
-        # pass 1: per-member bookkeeping (controller, health, hedging,
-        # rebalancing, preemption) — preemption's urgent re-admit still
-        # dispatches immediately (rare path; correctness over batching)
-        healthy = {}
-        for rep in cohort:
-            self._sched_for(rep).controller.maybe_update(t, self.feedback)
-            healthy[id(rep)] = self._healthy(rep)
-            self._maybe_hedge(rep, t)
-            if healthy[id(rep)]:
-                self._maybe_rebalance(rep, t)
-                self._maybe_preempt(rep, t)
-        # a rebalance can move a cohort-mate: drop removed members (the
-        # replacement joined at the frontier and steps next round)
-        cohort = [r for r in cohort if r in self.replicas]
-        # pass 2: scheduler flushes, STAGED (host half only) so every
-        # member's admissions fold into one grouped scatter
-        admit_stages, resume_stages = [], []
-        for rep in cohort:
-            sched = self._sched_for(rep)
-            free = rep.engine.num_free
-            if not healthy[id(rep)] or \
-                    not sched.should_flush(t, free, rep.engine.num_active):
-                continue
-            batch = sched.select(free, t)
-            if not batch:
-                continue
-            fresh = [r for r in batch if r.checkpoint is None]
-            resumed = [r for r in batch if r.checkpoint is not None]
-            if fresh:
-                admit_stages.append(rep.engine.stage_admit_batch(
-                    [(r.rid, r.qvec, self._params_for(r, rep))
-                     for r in fresh]))
-            if resumed:
-                resume_stages.append(rep.engine.stage_resume_batch(
-                    [(r.rid, r.checkpoint) for r in resumed]))
-                for req in resumed:
-                    req.checkpoint = None
-                self.metrics.resumes += len(resumed)
-            for req in batch:
-                rep.in_flight[req.rid] = req
-        self._group.dispatch_admits(admit_stages)
-        self._group.dispatch_restores(resume_stages)
-        # idle members jump their clocks exactly like the serial path
-        lanes = []
-        for rep in cohort:
-            if rep.engine.num_active > 0:
-                lanes.append(rep)
-                continue
-            sched = self._sched_for(rep)
-            if sched.queued() > 0:
-                rep.clock = t + sched.controller.tau_pre
-            elif self._pending:
-                rep.clock = max(t + 1e-9, min(self._pending[0][0], t_end))
-            else:
-                rep.clock = t_end
+        with tracing.span("schedule", members=len(cohort)):
+            # pass 1: per-member bookkeeping (controller, health, hedging,
+            # rebalancing, preemption) — preemption's urgent re-admit still
+            # dispatches immediately (rare path; correctness over batching)
+            healthy = {}
+            for rep in cohort:
+                self._sched_for(rep).controller.maybe_update(t,
+                                                             self.feedback)
+                healthy[id(rep)] = self._healthy(rep)
+                self._maybe_hedge(rep, t)
+                if healthy[id(rep)]:
+                    self._maybe_rebalance(rep, t)
+                    self._maybe_preempt(rep, t)
+            # a rebalance can move a cohort-mate: drop removed members (the
+            # replacement joined at the frontier and steps next round)
+            cohort = [r for r in cohort if r in self.replicas]
+            # pass 2: scheduler flushes, seated together so every member's
+            # admissions fold into one grouped scatter
+            flushes = []
+            for rep in cohort:
+                sched = self._sched_for(rep)
+                free = rep.engine.num_free
+                if not healthy[id(rep)] or not sched.should_flush(
+                        t, free, rep.engine.num_active):
+                    continue
+                batch = sched.select(free, t)
+                if batch:
+                    flushes.append((rep, batch))
+            if flushes:
+                self._admit_group(flushes)
+            # idle members jump their clocks exactly like the serial path
+            lanes = []
+            for rep in cohort:
+                if rep.engine.num_active > 0:
+                    lanes.append(rep)
+                    continue
+                sched = self._sched_for(rep)
+                if sched.queued() > 0:
+                    rep.clock = t + sched.controller.tau_pre
+                elif self._pending:
+                    rep.clock = max(t + 1e-9,
+                                    min(self._pending[0][0], t_end))
+                else:
+                    rep.clock = t_end
         if not lanes:
             return
         # ONE grouped dispatch: K extend steps over the whole cohort
         k = lanes[0].engine.extend_chunk
         pending_dev = self._group.step_lanes_async(
             [rep.engine.lane for rep in lanes], k)
-        dt_base = roofline_model.extend_time_group(cfg, len(lanes),
-                                                   self._double_buffer)
-        dt_of = {}
-        for rep in lanes:
-            dt = dt_base * rep.slowdown
-            dt_of[id(rep)] = dt
-            rep.clock = t + k * dt
-            rep.ext_latency_ewma = 0.9 * rep.ext_latency_ewma + 0.1 * dt
-            self._sched_for(rep).observe_extend_latency(dt)
-            self.metrics.extend_steps += k
-            self.metrics.tasks_capacity += k * cfg.task_batch
-        if self._double_buffer:
-            # double-buffered chunks: the grouped extend is in flight on
-            # device — run the next round's host-side arrival release
-            # BEFORE blocking on the completion masks (sim-time prices
-            # the overlap as max(host, dev) in extend_time_group)
-            self._release_pending(min(r.clock for r in self.replicas))
-        completed_k, tasks_k = jax.device_get(pending_dev)
+        with tracing.span("schedule", members=len(lanes)):
+            dt_base = roofline_model.extend_time_group(cfg, len(lanes),
+                                                       self._double_buffer)
+            dt_of = {}
+            for rep in lanes:
+                dt = dt_base * rep.slowdown
+                dt_of[id(rep)] = dt
+                rep.clock = t + k * dt
+                rep.ext_latency_ewma = 0.9 * rep.ext_latency_ewma + 0.1 * dt
+                self._sched_for(rep).observe_extend_latency(dt)
+                self.metrics.extend_steps += k
+                self.metrics.tasks_capacity += k * cfg.task_batch
+            if self._double_buffer:
+                # double-buffered chunks: the grouped extend is in flight
+                # on device — run the next round's host-side arrival
+                # release BEFORE blocking on the completion masks (sim-time
+                # prices the overlap as max(host, dev) in
+                # extend_time_group)
+                self._release_pending(min(r.clock for r in self.replicas))
+        with tracing.span("sync"):
+            completed_k, tasks_k = jax.device_get(pending_dev)
         # per-member engine/pool counters (mirrors step_multi exactly)
-        records = []
-        for rep in lanes:
-            eng = rep.engine
-            ck = completed_k[:, eng.lane]
-            tk = tasks_k[:, eng.lane]
-            self.metrics.tasks_emitted += int(tk.sum())
-            eng.total_tasks += int(tk.sum())
-            eng.total_capacity += k * cfg.task_batch
-            eng.steps += k
-            live = eng.num_active
-            per_step = ck.sum(axis=1)
-            for i in range(k):
-                eng.total_live_slots += live
-                live -= int(per_step[i])
-            if not ck.any():
-                continue
-            for i in range(k):
-                for slot in np.nonzero(ck[i])[0]:
-                    slot = int(slot)
-                    rid = eng.slot_request.pop(slot)
-                    kk = eng.slot_topk.pop(slot, cfg.top_k)
-                    eng.free_slots.append(slot)
-                    records.append([rep, rid, kk, i, slot, "host"])
+        with tracing.span("collect", done=int(completed_k.sum())):
+            records = []
+            for rep in lanes:
+                eng = rep.engine
+                ck = completed_k[:, eng.lane]
+                tk = tasks_k[:, eng.lane]
+                self.metrics.tasks_emitted += int(tk.sum())
+                eng.total_tasks += int(tk.sum())
+                if not ck.any():
+                    continue
+                for i in range(k):
+                    for slot in np.nonzero(ck[i])[0]:
+                        slot = int(slot)
+                        rid = eng.slot_request.pop(slot)
+                        kk = eng.slot_topk.pop(slot, cfg.top_k)
+                        eng.free_slots.append(slot)
+                        records.append([rep, rid, kk, i, slot, "host"])
         if records and self._device_merge:
             # a completing insert REWRITES its shard's gid map (cache
             # eviction can re-home a row), and the legacy serial loop
@@ -1316,7 +1324,35 @@ class ShardedVectorPool(VectorPool):
             self._scan_chunk_completions(records, t, dt_of)
         # grouped rescue snapshots: one gather + sync for the cohort
         if cfg.rescue_enabled:
-            self._refresh_snapshots(lanes)
+            with tracing.span("complete"):
+                self._refresh_snapshots(lanes)
+
+    def _admit_group(self, flushes):
+        """Seat every member's scheduler flush ``[(rep, batch), ...]``:
+        host halves staged per member (``stage_admit_batch`` /
+        ``stage_resume_batch``), then ONE grouped admit scatter and ONE
+        restore scatter."""
+        batches = [r for _, batch in flushes for r in batch]
+        with tracing.span("admit", n=len(batches),
+                          **tracing.rids(r.rid for r in batches)):
+            admit_stages, resume_stages = [], []
+            for rep, batch in flushes:
+                fresh = [r for r in batch if r.checkpoint is None]
+                resumed = [r for r in batch if r.checkpoint is not None]
+                if fresh:
+                    admit_stages.append(rep.engine.stage_admit_batch(
+                        [(r.rid, r.qvec, self._params_for(r, rep))
+                         for r in fresh]))
+                if resumed:
+                    resume_stages.append(rep.engine.stage_resume_batch(
+                        [(r.rid, r.checkpoint) for r in resumed]))
+                    for req in resumed:
+                        req.checkpoint = None
+                    self.metrics.resumes += len(resumed)
+                for req in batch:
+                    rep.in_flight[req.rid] = req
+            self._group.dispatch_admits(admit_stages)
+            self._group.dispatch_restores(resume_stages)
 
     def _scan_chunk_completions(self, records, t: float, dt_of):
         """Completion processing for one grouped chunk, in three phases.
@@ -1330,114 +1366,116 @@ class ShardedVectorPool(VectorPool):
         extends gather, then syncs ONCE. Phase C runs the legacy
         bookkeeping per completion in serial order; device-merged parents
         take their (k,) results straight from the finalize output."""
-        cfg = self.cfg
-        fold_entries, fold_rows, fold_cols = [], [], []
-        host_pos = {}  # record index -> host gather row
-        claimed: Set[tuple] = set()
-        accepted: Dict[int, Set[int]] = {}
-        for ridx, rec in enumerate(records):
-            rep, rid, kk, _i, slot, _route = rec
-            req = rep.in_flight[rid]
-            if not self._device_merge or req.kind == "insert":
-                host_pos[ridx] = len(host_pos)
-                continue
-            fan = self._fanout.get(req.parent_rid) \
-                if req.parent_rid is not None else None
-            s = req.shard
-            if fan is None or s not in fan.pending \
-                    or (req.parent_rid, s) in claimed:
-                rec[5] = "drop"
-                continue
-            claimed.add((req.parent_rid, s))
-            if fan.buf_row is None and not fan.host:
-                if self._buf_free:
-                    fan.buf_row = self._buf_free.pop()
-                else:
-                    fan.host = True  # buffer exhausted: sticky host path
-            if fan.buf_row is None:
-                host_pos[ridx] = len(host_pos)
-                continue
-            rec[5] = "dev"
-            if fan.kk is None:
-                fan.kk = kk
-            fold_entries.append((rep.engine.lane, slot))
-            fold_rows.append(fan.buf_row)
-            fold_cols.append(s)
-            accepted.setdefault(req.parent_rid, set()).add(s)
-        finalize = [self._fanout[prid] for prid, accs in accepted.items()
-                    if not (self._fanout[prid].pending - accs)
-                    and not self._fanout[prid].parent.failed]
+        with tracing.span("collect", done=len(records)):
+            cfg = self.cfg
+            fold_entries, fold_rows, fold_cols = [], [], []
+            host_pos = {}  # record index -> host gather row
+            claimed: Set[tuple] = set()
+            accepted: Dict[int, Set[int]] = {}
+            for ridx, rec in enumerate(records):
+                rep, rid, kk, _i, slot, _route = rec
+                req = rep.in_flight[rid]
+                if not self._device_merge or req.kind == "insert":
+                    host_pos[ridx] = len(host_pos)
+                    continue
+                fan = self._fanout.get(req.parent_rid) \
+                    if req.parent_rid is not None else None
+                s = req.shard
+                if fan is None or s not in fan.pending \
+                        or (req.parent_rid, s) in claimed:
+                    rec[5] = "drop"
+                    continue
+                claimed.add((req.parent_rid, s))
+                if fan.buf_row is None and not fan.host:
+                    if self._buf_free:
+                        fan.buf_row = self._buf_free.pop()
+                    else:
+                        fan.host = True  # buffer exhausted: sticky host path
+                if fan.buf_row is None:
+                    host_pos[ridx] = len(host_pos)
+                    continue
+                rec[5] = "dev"
+                if fan.kk is None:
+                    fan.kk = kk
+                fold_entries.append((rep.engine.lane, slot))
+                fold_rows.append(fan.buf_row)
+                fold_cols.append(s)
+                accepted.setdefault(req.parent_rid, set()).add(s)
+            finalize = [self._fanout[prid] for prid, accs in accepted.items()
+                        if not (self._fanout[prid].pending - accs)
+                        and not self._fanout[prid].parent.failed]
 
-        def pad1(xs):
-            pad = _pow2_pad(len(xs)) - len(xs)
-            return jnp.asarray(np.asarray(xs + xs[:1] * pad, np.int32))
+            def pad1(xs):
+                pad = _pow2_pad(len(xs)) - len(xs)
+                return jnp.asarray(np.asarray(xs + xs[:1] * pad, np.int32))
 
-        if fold_entries:
-            self._refresh_trans()
-            g_idx, slots_p = self._group._pad_pairs(fold_entries)
-            self._buf_ids, self._buf_dists = fold_partial_topk(
-                self._buf_ids, self._buf_dists, self._group.state.top_ids,
-                self._group.state.top_dists, self._trans, g_idx, slots_p,
-                pad1(fold_rows), pad1(fold_cols))
-        host_rows_dev = None
-        if host_pos:
-            g_idx, slots_p = self._group._pad_pairs(
-                [(records[j][0].engine.lane, records[j][4])
-                 for j in host_pos])
-            host_rows_dev = collect_slots_group(self._group.state, g_idx,
+            if fold_entries:
+                self._refresh_trans()
+                g_idx, slots_p = self._group._pad_pairs(fold_entries)
+                self._buf_ids, self._buf_dists = fold_partial_topk(
+                    self._buf_ids, self._buf_dists, self._group.state.top_ids,
+                    self._group.state.top_dists, self._trans, g_idx, slots_p,
+                    pad1(fold_rows), pad1(fold_cols))
+            host_rows_dev = None
+            if host_pos:
+                g_idx, slots_p = self._group._pad_pairs(
+                    [(records[j][0].engine.lane, records[j][4])
+                     for j in host_pos])
+                host_rows_dev = collect_slots_group(self._group.state, g_idx,
+                                                    slots_p)
+            ext_dev = None
+            if len(host_pos) < len(records):
+                g_idx, slots_p = self._group._pad_pairs(
+                    [(rec[0].engine.lane, rec[4]) for rec in records])
+                ext_dev = collect_extends_group(self._group.state, g_idx,
                                                 slots_p)
-        ext_dev = None
-        if len(host_pos) < len(records):
-            g_idx, slots_p = self._group._pad_pairs(
-                [(rec[0].engine.lane, rec[4]) for rec in records])
-            ext_dev = collect_extends_group(self._group.state, g_idx,
-                                            slots_p)
-        fin_dev = None
-        rows_f = [fan.buf_row for fan in finalize] + self._buf_dirty
-        if rows_f:
-            self._buf_ids, self._buf_dists, fin_ids, fin_d = \
-                finalize_partial_topk(self._buf_ids, self._buf_dists,
-                                      pad1(rows_f), k=cfg.top_m)
-            fin_dev = (fin_ids, fin_d)
-            self._buf_dirty = []
-        # the ONE bundled host-device sync for this chunk's results
-        host_rows, ext_all, fin_out = jax.device_get(
-            (host_rows_dev, ext_dev, fin_dev))
-        fin_index = {fan.buf_row: i for i, fan in enumerate(finalize)}
-        for ridx, rec in enumerate(records):
-            rep, rid, kk, i, slot, route = rec
-            req = rep.in_flight.pop(rid)
-            req.t_completed = t + (i + 1) * dt_of[id(rep)]
-            if route == "host":
-                pos = host_pos[ridx]
-                ids, dists, ext = host_rows
-                req.extends_used = int(ext[pos])
-                req.result_ids = ids[pos, :kk].copy()
-                req.result_dists = dists[pos, :kk].copy()
-                self._on_complete(req, rep)
-                continue
-            req.extends_used = int(ext_all[ridx])
-            if route == "drop":
-                self._on_complete(req, rep)  # legacy hedge-drop branch
-                continue
-            fan = self._fold_child_device(req, kk)
-            if fan is None or fan.pending:
-                continue
-            self._fanout.pop(req.parent_rid)
-            parent = fan.parent
-            if parent.failed or fan.buf_row is None:
-                self._finalize(fan)
-                continue
-            pos = fin_index[fan.buf_row]
-            parent.result_ids = fin_out[0][pos, :fan.kk].copy()
-            parent.result_dists = fin_out[1][pos, :fan.kk].copy()
-            self.metrics.merges += 1
-            parent.t_completed = fan.t_done
-            parent.extends_used = fan.extends
-            parent.t_admitted = fan.t_admitted
-            self.metrics.completed.append(parent)
-            self._buf_free.append(fan.buf_row)
-            fan.buf_row = None
+            fin_dev = None
+            rows_f = [fan.buf_row for fan in finalize] + self._buf_dirty
+            if rows_f:
+                self._buf_ids, self._buf_dists, fin_ids, fin_d = \
+                    finalize_partial_topk(self._buf_ids, self._buf_dists,
+                                          pad1(rows_f), k=cfg.top_m)
+                fin_dev = (fin_ids, fin_d)
+                self._buf_dirty = []
+            # the ONE bundled host-device sync for this chunk's results
+            host_rows, ext_all, fin_out = jax.device_get(
+                (host_rows_dev, ext_dev, fin_dev))
+        with tracing.span("complete", done=len(records)):
+            fin_index = {fan.buf_row: i for i, fan in enumerate(finalize)}
+            for ridx, rec in enumerate(records):
+                rep, rid, kk, i, slot, route = rec
+                req = rep.in_flight.pop(rid)
+                req.t_completed = t + (i + 1) * dt_of[id(rep)]
+                if route == "host":
+                    pos = host_pos[ridx]
+                    ids, dists, ext = host_rows
+                    req.extends_used = int(ext[pos])
+                    req.result_ids = ids[pos, :kk].copy()
+                    req.result_dists = dists[pos, :kk].copy()
+                    self._on_complete(req, rep)
+                    continue
+                req.extends_used = int(ext_all[ridx])
+                if route == "drop":
+                    self._on_complete(req, rep)  # legacy hedge-drop branch
+                    continue
+                fan = self._fold_child_device(req, kk)
+                if fan is None or fan.pending:
+                    continue
+                self._fanout.pop(req.parent_rid)
+                parent = fan.parent
+                if parent.failed or fan.buf_row is None:
+                    self._finalize(fan)
+                    continue
+                pos = fin_index[fan.buf_row]
+                parent.result_ids = fin_out[0][pos, :fan.kk].copy()
+                parent.result_dists = fin_out[1][pos, :fan.kk].copy()
+                self.metrics.merges += 1
+                parent.t_completed = fan.t_done
+                parent.extends_used = fan.extends
+                parent.t_admitted = fan.t_admitted
+                self.metrics.completed.append(parent)
+                self._buf_free.append(fan.buf_row)
+                fan.buf_row = None
 
     def _fold_child_device(self, req: VectorRequest, kk: int):
         """Host half of a device-folded child completion: the exact

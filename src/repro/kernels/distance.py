@@ -152,5 +152,6 @@ def distance_tasks(corpus, queries, task_ids, task_slot, *, metric: str = "l2",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T), jnp.float32),
         interpret=interpret,
+        name="distance_tasks",
     )(task_ids.astype(jnp.int32), corpus, queries, meta)
     return out[0]

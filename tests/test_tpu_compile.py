@@ -6,6 +6,7 @@ topology is described only inside the fixtures: only one process at a time
 may load the TPU library, so nothing here touches it at import.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +78,14 @@ def test_extend_multi_compiles_with_kernel(one_chip, monkeypatch):
         num_steps=cfg.extend_chunk, p=cfg.parents_per_step,
         task_batch=cfg.task_batch, use_pallas=True, metric=cfg.metric,
         distance_mode=cfg.distance_mode)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's instruction keeps its name, and the step's stages their
+    # scopes, for a trace to be read by (the padding fuses into the
+    # kernel's operands)
+    assert re.search(r"%distance_tasks\.\d+ = \S+ custom-call\(", text)
+    for scope in ("build_tasks", "distance", "merge_topm", "converge"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_megabatch_extend_compiles_with_kernel(one_chip, monkeypatch):

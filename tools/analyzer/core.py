@@ -279,9 +279,10 @@ def default_config() -> AnalyzerConfig:
     *reporting* on real host/device work rather than *behavior* in
     simulated time: the launch drivers time real compiles and decodes,
     the training host loop logs real step rates, and benchmarks measure
-    real dispatches. Sim-clock code (core/, serving/, vector/) is NOT
-    allowlisted — a wall-clock read there corrupts replayability and
-    fires.
+    real dispatches, and core/tracing.py's host spans time real work for
+    reporting only. The rest of the sim-clock code (core/, serving/,
+    vector/) is NOT allowlisted — a wall-clock read there corrupts
+    replayability and fires.
     """
     return AnalyzerConfig(allow={
         "DET002": (
@@ -298,6 +299,10 @@ def default_config() -> AnalyzerConfig:
              "wall-clock reporting on generator throughput, never fed "
              "into sim time (arrivals are stamped in sim seconds before "
              "the run starts)"),
+            ("src/repro/core/tracing.py",
+             "host spans time real host work for reporting (span totals "
+             "beside the profiler's annotations) — no pool, scheduler or "
+             "simulated clock reads them"),
         ),
     })
 
