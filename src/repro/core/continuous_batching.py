@@ -31,10 +31,11 @@ bit-identical to K sequential ``extend_step`` calls — asserted in
 tests/test_continuous_batching.py. Admission is likewise batched:
 ``admit_many`` seeds a whole scheduler batch in ONE jitted vmapped dispatch
 (batch padded to a power-of-two bucket by replicating row 0 — duplicate
-scatters write identical values) instead of one ``admit`` dispatch per
-request. Parent selection uses ``jax.lax.top_k`` on negated rank (O(M·p))
-instead of a full argsort (O(M log M)); ties break to the lower index in
-both, so selection is unchanged.
+scatters write identical values), fed from two host buffers and deriving
+each request's entry key itself, so no eager JAX op precedes it. Parent
+selection uses ``jax.lax.top_k`` on negated rank (O(M·p)) instead of a
+full argsort (O(M log M)); ties break to the lower index in both, so
+selection is unchanged.
 
 Per-slot search params (retrieval-class heterogeneity): each slot carries
 its own entry-point range (``entry_lo``/``entry_hi`` — index segment the
@@ -127,6 +128,13 @@ class SlotParams:
 
 DEFAULT_PARAMS = SlotParams()
 
+# Entry-point keys are derived from the request id, NOT from a sequentially
+# consumed stream: fold_in(engine key, rid & _RID_MASK). A request's search
+# result is then a pure function of (qvec, rid), independent of admission
+# order — preemption/re-admission reordering cannot perturb recall, and the
+# on/off benchmark arms return bit-identical result sets.
+_RID_MASK = 0x7FFFFFFF
+
 
 # ---------------------------------------------------------------------------
 # jitted slot admission
@@ -147,12 +155,11 @@ def _corpus_rows(rows, dim: int):
 
 def _seed_request(db, qvec, entry_key, entry_lo, entry_hi, *, top_m: int,
                   visited_slots: int, num_entries: int, metric: str):
-    """Shared seeding body for ``admit`` / ``admit_many``: random entry
-    points in ``[entry_lo, entry_hi)`` (the slot's index segment) + their
-    exact distances (metric-aware), padded to topM, entries inserted into a
-    fresh visited row. Keeping this in one place makes the per-request and
-    batched admission paths equivalent by construction. The range bounds
-    are traced scalars, so heterogeneous segments share one compile."""
+    """Seeding body of one request in ``admit_many``: random entry points
+    in ``[entry_lo, entry_hi)`` (the slot's index segment) + their exact
+    distances (metric-aware), padded to topM, entries inserted into a fresh
+    visited row. The range bounds are traced scalars, so heterogeneous
+    segments share one compile."""
     entries = jax.random.randint(entry_key, (num_entries,), entry_lo,
                                  entry_hi)
     x = _corpus_rows(db[entries], qvec.shape[-1])
@@ -174,49 +181,28 @@ def _seed_request(db, qvec, entry_key, entry_lo, entry_hi, *, top_m: int,
 
 @functools.partial(jax.jit, static_argnames=("num_entries", "metric"),
                    donate_argnums=(0,))
-def admit(state: EngineState, db, slot, qvec, entry_key, entry_lo, entry_hi,
-          budget, num_entries: int = 16, metric: str = "l2"):
-    """Place a new request into `slot`: reset state, seed topM with random
-    entry points (ids + exact distances) from the slot's index segment,
-    insert entries into visited, arm the extend budget."""
-    M = state.top_ids.shape[1]
-    V = state.visited.shape[1]
-    ids, dists, visited_row = _seed_request(
-        db, qvec, entry_key, entry_lo, entry_hi, top_m=M, visited_slots=V,
-        num_entries=num_entries, metric=metric)
-    return EngineState(
-        query_vecs=state.query_vecs.at[slot].set(qvec),
-        top_ids=state.top_ids.at[slot].set(ids),
-        top_dists=state.top_dists.at[slot].set(dists),
-        expanded=state.expanded.at[slot].set(jnp.zeros((M,), bool)),
-        visited=state.visited.at[slot].set(visited_row),
-        active=state.active.at[slot].set(True),
-        extends=state.extends.at[slot].set(0),
-        budget=state.budget.at[slot].set(budget),
-    )
+def admit_many(state: EngineState, db, base_key, cols, qvecs,
+               num_entries: int = 16, metric: str = "l2"):
+    """Seat a whole scheduler batch in one dispatch: reset each slot, seed
+    its topM with random entry points (ids + exact distances) from the
+    slot's index segment, insert them into visited, arm the extend budget.
 
-
-@functools.partial(jax.jit, static_argnames=("num_entries", "metric"),
-                   donate_argnums=(0,))
-def admit_many(state: EngineState, db, slots, qvecs, entry_keys, entry_los,
-               entry_his, budgets, num_entries: int = 16, metric: str = "l2"):
-    """Batched ``admit``: seed a whole scheduler batch in one dispatch.
-
-    slots (B,) int32 · qvecs (B, d) · entry_keys (B, 2) uint32 — one PRNG
-    subkey per request (the host derives it by folding the request id into
-    the engine key), so results are bit-identical to B sequential ``admit``
-    calls in any order (asserted in tests; both paths vmap/call the shared
-    ``_seed_request``). entry_los/entry_his/budgets (B,) int32 carry the
-    per-slot search params. Duplicate slots (the host pads batches by
-    replicating row 0) scatter identical values and are safe.
+    cols (B, 5) int32 holds per request its slot, request id masked to 31
+    bits, entry_lo, entry_hi and budget; qvecs (B, d). Each request's entry
+    key is ``fold_in(base_key, rid)``, derived here: the same bits as the
+    eager ``fold_in``, so a request's entry points are a pure function of
+    (base key, rid), independent of batch position and order. Duplicate
+    slots (the host pads batches by replicating row 0) scatter identical
+    values and are safe.
     """
     M = state.top_ids.shape[1]
     V = state.visited.shape[1]
+    slots, rids, los, his, budgets = (cols[:, i] for i in range(5))
     seed = functools.partial(_seed_request, top_m=M, visited_slots=V,
                              num_entries=num_entries, metric=metric)
     ids, dists, visited_rows = jax.vmap(
-        lambda q, k, lo, hi: seed(db, q, k, lo, hi))(
-        qvecs, entry_keys, entry_los, entry_his)
+        lambda q, r, lo, hi: seed(db, q, jax.random.fold_in(base_key, r),
+                                  lo, hi))(qvecs, rids, los, his)
     B = slots.shape[0]
     return EngineState(
         query_vecs=state.query_vecs.at[slots].set(qvecs),
@@ -491,14 +477,6 @@ class ContinuousBatchingEngine:
     def num_free(self) -> int:
         return len(self.free_slots)
 
-    def _entry_key(self, request_id):
-        # per-request entry-point key derived from the request id, NOT from
-        # a sequentially-consumed stream: a request's search result is then
-        # a pure function of (qvec, rid), independent of admission order —
-        # preemption/re-admission reordering cannot perturb recall, and the
-        # on/off benchmark arms return bit-identical result sets
-        return jax.random.fold_in(self._key, int(request_id) & 0x7FFFFFFF)
-
     def _resolve_params(self, params: Optional[SlotParams]):
         """(entry_lo, entry_hi, budget, top_k) with segment defaulting to
         the frozen corpus rows."""
@@ -507,27 +485,20 @@ class ContinuousBatchingEngine:
         return p.entry_lo, hi, p.budget, p.top_k
 
     def admit(self, request_id, qvec, params: Optional[SlotParams] = None) -> int:
-        slot = self.free_slots.pop()
-        lo, hi, budget, top_k = self._resolve_params(params)
-        self.state = admit(self.state, self.db, slot, jnp.asarray(qvec),
-                           self._entry_key(request_id), jnp.int32(lo),
-                           jnp.int32(hi), jnp.int32(budget),
-                           num_entries=min(16, self.cfg.top_m // 2),
-                           metric=self.cfg.metric)
-        self.slot_request[slot] = request_id
-        if top_k is not None:
-            self.slot_topk[slot] = top_k
-        return slot
+        return self.admit_batch([(request_id, qvec, params)])[0]
 
     def admit_batch(self, requests) -> List[int]:
         """Admit ``[(request_id, qvec), ...]`` — optionally
         ``(request_id, qvec, SlotParams)`` — in ONE jitted dispatch.
 
-        Entry keys are folded in per request id (same derivation as
-        ``admit``), and the batch is padded to a power-of-two bucket (by
-        replicating row 0 — duplicate scatters write identical values) so
-        only O(log max_requests) distinct shapes ever compile. Results are
-        bit-identical to sequential ``admit`` calls in any order."""
+        The host builds two NumPy buffers, an int32 table of (slot, masked
+        request id, entry_lo, entry_hi, budget) rows and the float32 query
+        block, and hands them to ``admit_many``, which derives the entry
+        keys itself: no eager JAX op runs before it. The batch is padded
+        to a power-of-two bucket (by replicating row 0 — duplicate scatters
+        write identical values) so only O(log max_requests) distinct shapes
+        ever compile. Results are bit-identical to sequential ``admit``
+        calls in any order."""
         if not requests:
             return []
         requests = [r if len(r) == 3 else (r[0], r[1], None)
@@ -535,21 +506,17 @@ class ContinuousBatchingEngine:
         B = len(requests)
         assert B <= len(self.free_slots), (B, len(self.free_slots))
         slots = [self.free_slots.pop() for _ in range(B)]
-        subs = [self._entry_key(rid) for rid, _, _ in requests]
         resolved = [self._resolve_params(p) for _, _, p in requests]
         b_pad = 1 << (B - 1).bit_length()
-        pad = b_pad - B
-        slots_p = np.asarray(slots + slots[:1] * pad, np.int32)
-        qvecs = np.stack([np.asarray(q, np.float32) for _, q, _ in requests])
-        qvecs_p = np.concatenate([qvecs] + [qvecs[:1]] * pad) if pad else qvecs
-        keys_p = jnp.stack(subs + subs[:1] * pad)
-        pcols = np.asarray([r[:3] for r in resolved], np.int32)
-        pcols_p = np.concatenate([pcols] + [pcols[:1]] * pad) if pad else pcols
-        self.state = admit_many(self.state, self.db, jnp.asarray(slots_p),
-                                jnp.asarray(qvecs_p), keys_p,
-                                jnp.asarray(pcols_p[:, 0]),
-                                jnp.asarray(pcols_p[:, 1]),
-                                jnp.asarray(pcols_p[:, 2]),
+        cols = np.empty((b_pad, 5), np.int32)
+        qvecs = np.empty((b_pad, self.cfg.dim), np.float32)
+        for i, (slot, (rid, q, _), (lo, hi, budget, _)) in enumerate(
+                zip(slots, requests, resolved)):
+            cols[i] = (slot, int(rid) & _RID_MASK, lo, hi, budget)
+            qvecs[i] = q
+        cols[B:] = cols[0]
+        qvecs[B:] = qvecs[0]
+        self.state = admit_many(self.state, self.db, self._key, cols, qvecs,
                                 num_entries=min(16, self.cfg.top_m // 2),
                                 metric=self.cfg.metric)
         for slot, (rid, _, _), (_, _, _, top_k) in zip(slots, requests,
@@ -1128,6 +1095,9 @@ class GroupMember(ContinuousBatchingEngine):
         self.total_tasks = 0
 
     # ------------------------------------------------------- admission
+    def _entry_key(self, request_id):
+        return jax.random.fold_in(self._key, int(request_id) & _RID_MASK)
+
     def stage_admit_batch(self, requests) -> dict:
         """Host half of ``admit_batch``: pop slots, fold per-request PRNG
         keys, resolve per-slot params — returns the staged device args

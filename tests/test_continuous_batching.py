@@ -226,3 +226,128 @@ def test_early_exit_no_infinite_loop(setup):
     rid, ids, dists, ext = out[0]
     assert 0 < ext <= 128
     assert np.all(np.diff(dists) >= -1e-5)
+
+
+def _load_ann_ref():
+    """The benchmark's reference search (bench/ann_ref.py), by path: it
+    derives entry points in ``jit`` over a uint32 rid vector, with nothing
+    taken from the program."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "ann_ref.py"
+    spec = importlib.util.spec_from_file_location("_bench_ann_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_BIG_RIDS = [2**31 - 1, 2**31, 2**31 + 5, 4_100_000_000, 2**28]
+
+
+@pytest.mark.parametrize("case", ["odd_batch", "rids_above_2_31",
+                                  "slot_params"])
+def test_admit_batch_matches_eager_key_derivation(setup, case):
+    """``admit_many`` derives each entry key in-program; the seeded state
+    must equal, field by field, one built from the eager derivation it
+    replaced: ``fold_in(PRNGKey(seed), rid & 0x7FFFFFFF)`` per request,
+    fed to ``_seed_request``."""
+    import functools
+
+    import jax
+
+    from repro.core.continuous_batching import (SlotParams, _seed_request,
+                                                init_engine_state)
+
+    cfg, db, graph, queries, _ = setup
+    seed = 21
+    if case == "odd_batch":
+        reqs = [(i, queries[i], None) for i in range(7)]
+    elif case == "rids_above_2_31":
+        reqs = [(rid, queries[i], None) for i, rid in enumerate(_BIG_RIDS)]
+    else:
+        reqs = [(40 + i, queries[i],
+                 SlotParams(entry_lo=100 * i, entry_hi=100 * i + 500 + i,
+                            budget=3 * i, top_k=5))
+                for i in range(5)]
+    eng = ContinuousBatchingEngine(cfg, db, graph, use_pallas=False,
+                                   seed=seed)
+    slots = eng.admit_batch(reqs)
+
+    ref = jax.tree_util.tree_map(np.array, init_engine_state(cfg))
+    num_entries = min(16, cfg.top_m // 2)
+    seed_one = jax.jit(functools.partial(
+        _seed_request, top_m=cfg.top_m, visited_slots=cfg.visited_slots,
+        num_entries=num_entries, metric=cfg.metric))
+    base = jax.random.PRNGKey(seed)
+    for slot, (rid, q, params) in zip(slots, reqs):
+        params = params or SlotParams()
+        hi = params.entry_hi or eng.corpus_n
+        key = jax.random.fold_in(base, rid & 0x7FFFFFFF)
+        ids, dists, visited = seed_one(eng.db, jnp.asarray(q), key,
+                                       jnp.int32(params.entry_lo),
+                                       jnp.int32(hi))
+        ref.query_vecs[slot] = q
+        ref.top_ids[slot] = ids
+        ref.top_dists[slot] = dists
+        ref.expanded[slot] = False
+        ref.visited[slot] = visited
+        ref.active[slot] = True
+        ref.extends[slot] = 0
+        ref.budget[slot] = params.budget
+    for field in ("query_vecs", "top_ids", "top_dists", "expanded",
+                  "visited", "active", "extends", "budget"):
+        np.testing.assert_array_equal(np.asarray(getattr(eng.state, field)),
+                                      getattr(ref, field), err_msg=field)
+    if case == "slot_params":
+        assert eng.slot_topk == {s: 5 for s in slots}
+
+
+def test_admit_batch_entry_points_match_bench_reference(setup):
+    """The benchmark's ``correct`` check rests on this agreement: the
+    engine's seeded entry ids equal ``bench/ann_ref.entry_points`` for the
+    same seed and rids, masked rids above 2**31 included."""
+    cfg, db, graph, queries, _ = setup
+    ann_ref = _load_ann_ref()
+    seed = 4_100_000_021
+    rids = [0, 5, 12345] + _BIG_RIDS
+    eng = ContinuousBatchingEngine(cfg, db, graph, use_pallas=False,
+                                   seed=seed)
+    slots = eng.admit_batch([(rid, queries[i]) for i, rid in enumerate(rids)])
+    num = min(16, cfg.top_m // 2)
+    want = ann_ref.entry_points(seed, np.asarray(rids, np.int64),
+                                eng.corpus_n, num)
+    got = np.asarray(eng.state.top_ids)[slots, :num]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("B", [1, 5])
+def test_admit_batch_is_one_program(setup, monkeypatch, B):
+    """After a warm call, a flush of B requests makes exactly one call into
+    the jitted ``admit_many`` and runs no eager key fold, stack or
+    host-to-device ``asarray`` before it."""
+    import jax
+
+    from repro.core import continuous_batching as cb
+
+    cfg, db, graph, queries, _ = setup
+    eng = ContinuousBatchingEngine(cfg, db, graph, use_pallas=False)
+    eng.admit_batch([(100 + i, queries[i]) for i in range(B)])  # compile
+    calls = {}
+
+    def spy(owner, name):
+        real = getattr(owner, name)
+
+        def wrapped(*args, **kwargs):
+            calls[name] = calls.get(name, 0) + 1
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(owner, name, wrapped)
+
+    spy(cb, "admit_many")
+    spy(jax.random, "fold_in")
+    spy(jnp, "stack")
+    spy(jnp, "asarray")
+    slots = eng.admit_batch([(200 + i, queries[B + i]) for i in range(B)])
+    assert calls == {"admit_many": 1}
+    assert len(slots) == B and eng.num_active == 2 * B
