@@ -17,7 +17,14 @@ dists, expanded flags, visited hash table). Every engine iteration:
      distance batch.
 
 The whole step is one jitted fixed-shape function (the CUDA-graph analogue)
-— state in, state out, no recompiles.
+— state in, state out — per *slot bucket*: ``extend_multi`` runs over a
+sub-state of ``Rb`` slots, the active ones first, with a task array of
+``task_batch · Rb / R``, and scatters it back. The buckets are the powers
+of two from the smallest slot count whose task array fills one kernel task
+block up to R (``slot_buckets``); the engine passes the smallest bucket
+that holds ``num_active``. Every slot's math is its own, so answers, extend
+and task counts are bit-identical to the R-slot step, and only
+O(log R) shapes ever compile.
 
 Fused multi-extend stepping (the dispatch-overhead fix): the host loop used
 to re-cross the host-device boundary every step (one jitted dispatch + a
@@ -295,6 +302,16 @@ def restore_slots(state: EngineState, slots, query_vecs, top_ids, top_dists,
 # ---------------------------------------------------------------------------
 
 
+def slot_buckets(cfg) -> Tuple[int, ...]:
+    """The slot counts ``extend_multi`` may run over, ascending: each power
+    of two below R whose task array, ``task_batch · Rb / R``, is a whole
+    number of the distance kernel's task blocks, then R itself."""
+    R, T, block = cfg.max_requests, cfg.task_batch, kernel_ops.TASK_BLOCK
+    small = [1 << i for i in range(R.bit_length())
+             if (1 << i) < R and T * (1 << i) % (R * block) == 0]
+    return tuple(small) + (R,)
+
+
 def _build_tasks(state: EngineState, graph, p: int):
     """Stages 1–3: parent selection, neighbour gather, visited filter,
     global task emission. Returns (task_ids, task_slot (R*p*D,), updated
@@ -404,27 +421,45 @@ def extend_step(state: EngineState, db, graph, *, p: int, task_batch: int,
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "p", "use_pallas",
                                              "task_batch", "metric",
-                                             "distance_mode"),
+                                             "distance_mode", "num_slots"),
                    donate_argnums=(0,))
 def extend_multi(state: EngineState, db, graph, *, num_steps: int, p: int,
                  task_batch: int, use_pallas: bool = False,
-                 metric: str = "l2", distance_mode: str = "slot_gather"):
+                 metric: str = "l2", distance_mode: str = "slot_gather",
+                 num_slots: Optional[int] = None):
     """K fused engine iterations in ONE dispatch (``lax.scan`` over
     ``_extend_impl``). Requests that complete at sub-step i stay inactive
     (and their slot state untouched) for the remaining sub-steps, so the
     result is bit-identical to K sequential ``extend_step`` calls.
 
+    ``num_slots`` (a bucket of ``slot_buckets``; default R) runs the scan
+    over that many slots, active first and then inactive ones, each slot
+    once, with ``task_batch · num_slots / R`` tasks; the caller keeps every
+    active slot inside it. Slots left out are untouched.
+
     Returns (new_state, completed (K, R) bool, tasks_emitted (K,) int32) —
     stacked device arrays; the host syncs once per K steps."""
+    R = state.active.shape[0]
+    rb = R if num_slots is None else num_slots
+    assert 0 < rb <= R and task_batch * rb % R == 0, (rb, R, task_batch)
 
     def body(st, _):
         st, completed, tasks = _extend_impl(
-            st, db, graph, p=p, task_batch=task_batch, use_pallas=use_pallas,
-            metric=metric, distance_mode=distance_mode)
+            st, db, graph, p=p, task_batch=task_batch * rb // R,
+            use_pallas=use_pallas, metric=metric,
+            distance_mode=distance_mode)
         return st, (completed, tasks)
 
-    state, (completed_k, tasks_k) = jax.lax.scan(
-        body, state, None, length=num_steps)
+    if rb == R:  # every slot: the state as it lies, no gather
+        state, (completed_k, tasks_k) = jax.lax.scan(
+            body, state, None, length=num_steps)
+        return state, completed_k, tasks_k
+    idx = jnp.argsort(~state.active, stable=True)[:rb]
+    sub = jax.tree_util.tree_map(lambda x: x[idx], state)
+    sub, (completed_k, tasks_k) = jax.lax.scan(
+        body, sub, None, length=num_steps)
+    state = jax.tree_util.tree_map(lambda x, y: x.at[idx].set(y), state, sub)
+    completed_k = jnp.zeros((num_steps, R), bool).at[:, idx].set(completed_k)
     return state, completed_k, tasks_k
 
 
@@ -443,8 +478,9 @@ class ContinuousBatchingEngine:
     freelist/slot-map already knows it — no device readback), admissions go
     through one vmapped ``admit_many`` dispatch per scheduler batch
     (``admit_batch``), and ``step_multi`` fuses K extend steps into one
-    device dispatch with a single host sync for the stacked completion
-    masks + task counts.
+    device dispatch over the smallest slot bucket that holds ``num_active``,
+    with a single host sync for the stacked completion masks + task counts.
+    ``dispatched_slots`` is the bucket of the last dispatch.
     """
 
     def __init__(self, cfg, db: np.ndarray, graph: np.ndarray,
@@ -467,6 +503,8 @@ class ContinuousBatchingEngine:
         self.extend_chunk = max(1, cfg.extend_chunk)
         self._key = jax.random.PRNGKey(seed)
         self.total_tasks = 0  # distance tasks emitted, summed over steps
+        self.slot_buckets = slot_buckets(cfg)
+        self.dispatched_slots = cfg.max_requests
 
     @property
     def num_active(self) -> int:
@@ -622,19 +660,22 @@ class ContinuousBatchingEngine:
         return slots
 
     def step_multi(self, num_steps: Optional[int] = None):
-        """K fused extends over all active slots — one dispatch, one sync.
+        """K fused extends over all active slots — one dispatch, one sync,
+        over the smallest slot bucket that holds them.
 
         Returns (completions, tasks_per_step (K,) np.int32); completions
         are (request_id, topk_ids, topk_dists, extends_used, substep) with
         ``substep`` ∈ [0, K) the extend at which the request converged (for
         exact completion-time attribution in the pool)."""
         k = self.extend_chunk if num_steps is None else num_steps
-        with tracing.span("dispatch", active=self.num_active):
+        rb = next(b for b in self.slot_buckets if b >= self.num_active)
+        self.dispatched_slots = rb
+        with tracing.span("dispatch", active=self.num_active, slots=rb):
             self.state, completed_k, tasks_k = extend_multi(
                 self.state, self.db, self.graph, num_steps=k,
                 p=self.cfg.parents_per_step, task_batch=self.cfg.task_batch,
                 use_pallas=self.use_pallas, metric=self.cfg.metric,
-                distance_mode=self.distance_mode)
+                distance_mode=self.distance_mode, num_slots=rb)
         # the ONE host-device sync for this dispatch
         with tracing.span("sync"):
             completed_k, tasks_k = jax.device_get((completed_k, tasks_k))

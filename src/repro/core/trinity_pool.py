@@ -684,7 +684,10 @@ class VectorPool:
             sched.observe_extend_latency(dt)
             self.metrics.extend_steps += k
             self.metrics.tasks_emitted += int(tasks_k.sum())
-            self.metrics.tasks_capacity += k * self.cfg.task_batch
+            # the task array of the slot bucket actually dispatched
+            self.metrics.tasks_capacity += (
+                k * self.cfg.task_batch * rep.engine.dispatched_slots
+                // self.cfg.max_requests)
 
             for rid, ids, dists, extends, substep in completions:
                 req = rep.in_flight.pop(rid)

@@ -36,6 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DUMMY_DIST = 1e30
 LANES = 128
+TASK_BLOCK = 256  # tasks per grid step
 
 
 def corpus_layout(db):
@@ -107,7 +108,7 @@ def _distance_kernel(task_ids_sref, db_ref, queries_ref, meta_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("metric", "task_block",
                                              "interpret"))
 def distance_tasks(corpus, queries, task_ids, task_slot, *, metric: str = "l2",
-                   task_block: int = 256, interpret: bool = True):
+                   task_block: int = TASK_BLOCK, interpret: bool = True):
     """Fixed-shape distance stage; oracle is ``ref.distance_tasks_ref``.
 
     corpus (N, 1, d_pad) from ``corpus_layout`` · queries (R, d), d ≤ d_pad ·

@@ -96,10 +96,11 @@ def finalize_partial_topk(buf_ids, buf_dists, rows_f, *, k: int):
 
 
 corpus_layout = _dist.corpus_layout
+TASK_BLOCK = _dist.TASK_BLOCK
 
 
 def distance_tasks(corpus, queries, task_ids, task_slot, metric: str = "l2",
-                   task_block: int = 256):
+                   task_block: int = TASK_BLOCK):
     """``corpus`` is in the kernel's layout (``corpus_layout``)."""
     return _dist.distance_tasks(corpus, queries, task_ids, task_slot,
                                 metric=metric, task_block=task_block,

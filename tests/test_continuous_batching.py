@@ -351,3 +351,129 @@ def test_admit_batch_is_one_program(setup, monkeypatch, B):
     slots = eng.admit_batch([(200 + i, queries[B + i]) for i in range(B)])
     assert calls == {"admit_many": 1}
     assert len(slots) == B and eng.num_active == 2 * B
+
+
+@pytest.fixture(scope="module")
+def wide(setup):
+    """R = 64, p = 2, D = 16 and a 2048-task batch: slot buckets 8, 16, 32
+    and 64. Queries are corpus rows with a little noise, 160 of them."""
+    import dataclasses
+
+    cfg, db, graph, _, _ = setup
+    cfg = dataclasses.replace(cfg, max_requests=64, task_batch=2048)
+    rng = np.random.default_rng(17)
+    rows = db[rng.choice(len(db), 160, replace=False)]
+    queries = (rows + 0.05 * rng.standard_normal(rows.shape)).astype(
+        np.float32)
+    return cfg, db, graph, queries
+
+
+def _active_counts_agree(eng):
+    assert eng.num_active == int(np.asarray(eng.state.active).sum())
+
+
+@pytest.mark.parametrize("occupancy,use_pallas", [
+    (1, False), (8, False), (9, False), (16, False), (17, False),
+    (33, False), (64, False), (9, True)])
+def test_slot_buckets_bit_identical_to_full_step(wide, occupancy,
+                                                 use_pallas):
+    """``step_multi`` over the smallest slot bucket that holds the active
+    slots returns what the 64-slot ``extend_multi`` returns: ids, dists,
+    extend counts, sub-steps and task counts, through completions inside
+    a chunk, exhausted budgets, and a preemption resumed two chunks later.
+    After every step the host's active count is the device's."""
+    from repro.core.continuous_batching import SlotParams, slot_buckets
+
+    cfg, db, graph, queries = wide
+    assert slot_buckets(cfg) == (8, 16, 32, 64)
+    bkt = ContinuousBatchingEngine(cfg, db, graph, use_pallas=use_pallas,
+                                   seed=21)
+    full = ContinuousBatchingEngine(cfg, db, graph, use_pallas=use_pallas,
+                                    seed=21)
+    full.slot_buckets = (cfg.max_requests,)
+    engines = (bkt, full)
+    next_rid = [0]
+
+    def top_up(n):
+        reqs = []
+        while bkt.num_active + len(reqs) < n and next_rid[0] < len(queries):
+            rid = next_rid[0]
+            params = SlotParams(budget=3) if rid % 5 == 0 else None
+            reqs.append((rid, queries[rid], params))
+            next_rid[0] += 1
+        for eng in engines:
+            eng.admit_batch(reqs)
+
+    top_up(occupancy)
+    parked, done, dispatched = [], [], set()
+    for chunk in range(200):
+        if bkt.num_active == 0 and not parked:
+            break
+        active = bkt.num_active
+        got, tasks_b = bkt.step_multi()
+        want, tasks_f = full.step_multi()
+        assert bkt.dispatched_slots == min(
+            b for b in bkt.slot_buckets if b >= active)
+        assert full.dispatched_slots == cfg.max_requests
+        dispatched.add(bkt.dispatched_slots)
+        np.testing.assert_array_equal(tasks_b, tasks_f)
+        assert [c[0] for c in got] == [c[0] for c in want]
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g[1], w[1])  # ids
+            np.testing.assert_array_equal(g[2], w[2])  # dists
+            assert g[3:] == w[3:]  # extends, substep
+        done.extend(got)
+        for eng in engines:
+            _active_counts_agree(eng)
+        if chunk == 1:
+            victims = sorted(bkt.slot_request.values())[:2]
+            ckpts = [eng.preempt(victims) for eng in engines]
+            parked = ckpts[0]
+            assert [r for r, _ in ckpts[1]] == [r for r, _ in parked]
+        elif chunk == 3:
+            for eng in engines:
+                eng.resume_batch(parked)
+            parked = []
+        if chunk < 6:
+            top_up(occupancy - len(parked))
+        for eng in engines:
+            _active_counts_agree(eng)
+    assert bkt.num_active == 0 and not parked
+    K = cfg.extend_chunk
+    assert any(c[4] < K - 1 for c in done)  # completed mid-chunk
+    assert any(c[0] % 5 == 0 and c[3] == 3 for c in done)  # budget spent
+    # the drain ends in the smallest bucket
+    assert min(dispatched) == 8 and max(dispatched) >= occupancy
+
+
+def test_bucket_is_smallest_holding_active_and_compiles_once(wide,
+                                                             monkeypatch):
+    """Through every occupancy from 1 to R, the engine dispatches the
+    smallest bucket that holds ``num_active`` (R when full), and
+    ``extend_multi`` compiles one program per bucket and chunk length."""
+    from repro.core import continuous_batching as cb
+
+    cfg, db, graph, queries = wide
+    eng = ContinuousBatchingEngine(cfg, db, graph, use_pallas=False, seed=5)
+    real = cb.extend_multi
+    seen = []
+
+    def spy(*args, **kwargs):
+        seen.append((kwargs["num_steps"], kwargs["num_slots"]))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(cb, "extend_multi", spy)
+    real.clear_cache()
+    rid = 0
+    for n in range(1, cfg.max_requests + 1):
+        reqs = []
+        while eng.num_active + len(reqs) < n:
+            reqs.append((rid, queries[rid % len(queries)]))
+            rid += 1
+        eng.admit_batch(reqs)
+        assert eng.num_active == n
+        eng.step_multi()
+        assert seen[-1][1] == min(b for b in (8, 16, 32, 64) if b >= n)
+    assert seen[-1][1] == cfg.max_requests
+    assert set(seen) == {(cfg.extend_chunk, b) for b in (8, 16, 32, 64)}
+    assert real._cache_size() == len(set(seen))

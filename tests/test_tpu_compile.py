@@ -63,9 +63,11 @@ def test_distance_kernel_compiles(one_chip, dim):
     assert compiled.memory_analysis().temp_size_in_bytes < N * dim
 
 
-def test_extend_multi_compiles_with_kernel(one_chip, monkeypatch):
+@pytest.mark.parametrize("num_slots", cb.slot_buckets(VectorPoolConfig()))
+def test_extend_multi_compiles_with_kernel(one_chip, monkeypatch, num_slots):
     """The fused engine step at the ``VectorPoolConfig`` defaults, on the
-    kernel (on this CPU host ``_interpret`` would say True)."""
+    kernel (on this CPU host ``_interpret`` would say True), over each
+    slot bucket: Mosaic's tiling of each bucket's task array."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = VectorPoolConfig()
@@ -77,13 +79,15 @@ def test_extend_multi_compiles_with_kernel(one_chip, monkeypatch):
         state, _corpus(cfg.num_vectors, cfg.dim, one_chip), graph,
         num_steps=cfg.extend_chunk, p=cfg.parents_per_step,
         task_batch=cfg.task_batch, use_pallas=True, metric=cfg.metric,
-        distance_mode=cfg.distance_mode)
+        distance_mode=cfg.distance_mode, num_slots=num_slots)
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     # the kernel's instruction keeps its name, and the step's stages their
     # scopes, for a trace to be read by (the padding fuses into the
-    # kernel's operands)
-    assert re.search(r"%distance_tasks\.\d+ = \S+ custom-call\(", text)
+    # kernel's operands); its output is the bucket's task array
+    tasks = cfg.task_batch * num_slots // cfg.max_requests
+    assert re.search(rf"%distance_tasks\.\d+ = f32\[1,{tasks}\]\S* "
+                     r"custom-call\(", text)
     for scope in ("build_tasks", "distance", "merge_topm", "converge"):
         assert f"/{scope}/" in text, scope
 

@@ -130,6 +130,29 @@ def test_spans_in_a_profiler_trace(setup, tmp_path):
     assert rids == sorted(r.rid for r in pool.metrics.completed)
 
 
+def test_dispatch_span_names_its_slot_bucket(setup, tmp_path):
+    """Each ``trinity.dispatch`` carries the slot bucket it ran over, the
+    smallest that holds its active slots, and ``tasks_capacity`` counts
+    those buckets' task arrays."""
+    from repro.core.continuous_batching import slot_buckets
+
+    cfg, db, graph, queries = setup
+    buckets = slot_buckets(cfg)
+    assert buckets == (2, 4, 8)
+    serve = lambda pool: _serve(pool, queries, n=30, gap=2e-5)
+    serve(VectorPool(cfg, db, graph, use_pallas=False, seed=0))
+    pool = VectorPool(cfg, db, graph, use_pallas=False, seed=0)
+    _, spans = _traced(tmp_path, lambda: serve(pool))
+    args = [s[4] for s in spans if s[0] == "trinity.dispatch"]
+    assert args
+    for a in args:
+        assert a["slots"] == min(b for b in buckets if b >= a["active"])
+    assert len({a["slots"] for a in args}) > 1
+    R, k = cfg.max_requests, cfg.extend_chunk
+    assert pool.metrics.tasks_capacity == sum(
+        k * cfg.task_batch * a["slots"] // R for a in args)
+
+
 def test_spans_of_a_grouped_chunk(setup, tmp_path):
     _, db, _, queries = setup
     cfg = VectorPoolConfig(num_vectors=1500, dim=32, graph_degree=16,
